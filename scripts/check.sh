@@ -7,6 +7,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# CPU only, Pallas in interpret mode; the chip path is chip_smoke.py
+export JAX_PLATFORMS=cpu
 
 echo "== tier-1 pytest =="
 # the two seed-era deselects (jamba hybrid decode drift, q4 decode top-1

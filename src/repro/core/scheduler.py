@@ -108,8 +108,10 @@ def make_virtual_accelerators(mesh, fractions=(0.25, 0.75)
     sl_dec = [slice(None)] * mesh.devices.ndim
     sl_enc[axis] = slice(0, cut)
     sl_dec[axis] = slice(cut, n)
-    enc_mesh = Mesh(mesh.devices[tuple(sl_enc)], mesh.axis_names)
-    dec_mesh = Mesh(mesh.devices[tuple(sl_dec)], mesh.axis_names)
+    enc_mesh = Mesh(mesh.devices[tuple(sl_enc)], mesh.axis_names,
+                    axis_types=mesh.axis_types)
+    dec_mesh = Mesh(mesh.devices[tuple(sl_dec)], mesh.axis_names,
+                    axis_types=mesh.axis_types)
     scale = lambda f: dataclasses.replace(
         TPU_V5E, peak_flops=TPU_V5E.peak_flops * f,
         hbm_bw=TPU_V5E.hbm_bw * f)

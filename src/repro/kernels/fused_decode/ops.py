@@ -146,7 +146,7 @@ def _fused_cohort_step(params, cfg, tokens, lengths, slot_ids, tables,
         h = apply_norm(sub["norm1"], x)
         q, k_new, v_new = fused_qkv(
             h, mix["wq"], mix["wk"], mix["wv"],
-            mix.get("bq"), mix.get("bk"), mix.get("bv"),
+            *(_dq(mix.get(b)) for b in ("bq", "bk", "bv")),
             interpret=interpret)
         q, k_new = rope_fn(q), rope_fn(k_new)
         o = attn.attn_context(q, k_new, v_new, ck, cv, index, cfg)

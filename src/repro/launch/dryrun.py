@@ -1,4 +1,7 @@
 import os
+# a dry-run compiles for placeholder host devices only: pinned to the CPU
+# so that on a machine with a TPU it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -32,7 +35,7 @@ from repro.analysis import roofline as rl
 from repro.configs import SHAPES, cell_applicable, get_config, list_archs
 from repro.distributed import sharding as sh
 from repro.launch import steps as st
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.training.optimizer import OptConfig
 
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -157,7 +160,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir=None,
         return rec
 
     if reduced:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = mesh.devices.size

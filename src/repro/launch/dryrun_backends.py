@@ -11,7 +11,10 @@ backend path can rot without TPU hardware.
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-# ^ must run before any jax import — jax locks the device count at init
+# pinned to the CPU's placeholder devices: never takes a TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both must run before any jax import — jax locks the platform and the
+# device count at init
 
 import argparse
 import sys
@@ -24,13 +27,14 @@ from repro.configs import get_config, list_archs
 from repro.core.bricks import decompose
 from repro.core.plan import compile_plan
 from repro.core.scheduler import make_virtual_accelerators
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import init_params
 
 
 def lower_and_run(cfg, graph, params, inputs, name: str):
     """Compile the graph under one backend lowering; return its logits."""
     if name == "submesh":
-        mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+        mesh = make_mesh((1, jax.device_count()), ("data", "model"))
         accels = make_virtual_accelerators(mesh, fractions=(0.25, 0.75))
         enc, dec = accels
         assignment = {b.name: (enc.name if b.static_shape else dec.name)

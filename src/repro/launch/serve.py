@@ -5,7 +5,8 @@
 
 Submits synthetic prompts (+ stub vision features for vlm archs), runs the
 engine to completion, prints the paper's metrics (tokens/s, end-to-end
-latency, memory, modeled watts/hours).
+latency, memory, modeled watts/hours).  Exits nonzero when any request
+fails or goes unserved.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ import numpy as np
 from repro.analysis.energy import EDGE_GPU, hours_on_battery, watts
 from repro.configs import get_config, list_archs
 from repro.core.power import BatteryAwareExecutor, PMU
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_params
 from repro.serving.engine import Request, ServingEngine
 from repro.telemetry.calibration import CostCalibration
@@ -30,7 +32,9 @@ def main(argv=None):
                     choices=list_archs())
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--max-len", type=int, default=512)
+    ap.add_argument("--max-len", type=int, default=None,
+                    help="per-slot KV length (default 512, or 2048 with "
+                         "--full so a 729-patch image plus prompt fits)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--battery", type=float, default=1.0)
     ap.add_argument("--quantize", default=None,
@@ -42,6 +46,9 @@ def main(argv=None):
                          "the engine's energy governor, and atomically "
                          "re-save the measured table on shutdown")
     args = ap.parse_args(argv)
+    if args.max_len is None:
+        args.max_len = 2048 if args.full else 512
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if cfg.encdec:
@@ -68,10 +75,13 @@ def main(argv=None):
 
     rng = np.random.default_rng(0)
     for i in range(args.requests):
-        n = int(rng.integers(8, 64))
-        req = Request(rid=i, tokens=rng.integers(
-            3, cfg.vocab_size - 1, n).astype(np.int32),
-            max_new_tokens=args.max_new)
+        tokens = rng.integers(3, cfg.vocab_size - 1, int(rng.integers(8, 64)))
+        if cfg.vlm:
+            # one placeholder per image patch ahead of the text: the
+            # projected patches replace their embeddings (model._embed)
+            tokens = np.concatenate([np.zeros(cfg.vision_tokens), tokens])
+        req = Request(rid=i, tokens=tokens.astype(np.int32),
+                      max_new_tokens=args.max_new)
         if cfg.vlm:
             req.vision_feats = rng.standard_normal(
                 (1, cfg.vision_tokens, cfg.vision_feat_dim)
@@ -111,6 +121,12 @@ def main(argv=None):
         table.save(args.calibration)
         print(f"  calibration: saved {len(table)} entries to "
               f"{args.calibration}")
+    failed = [r for r in done if r.error is not None]
+    if failed or len(done) != args.requests:
+        raise SystemExit(
+            f"serve: {len(failed)} failed, {args.requests - len(done)} "
+            f"unserved of {args.requests} requests"
+            + (f"; first error: {failed[0].error}" if failed else ""))
 
 
 if __name__ == "__main__":

@@ -31,7 +31,11 @@ Every run asserts the acceptance bar:
 ``--transport pipe`` / ``socket`` spawn the decode fleet as a real
 subprocess (``--role decode`` plus fd / port plumbing below) that
 re-initializes identical params from the same seed — nothing but frames
-crosses the boundary.
+crosses the boundary.  A TPU chip belongs to one process, and both
+fleets need a device, so on a TPU these two refuse to start: use
+``--transport inproc`` there (both fleets in one process, on the device
+ordinals ``core/scheduler.fleet_accelerators`` picks from the visible
+device count).
 """
 import argparse
 import subprocess
@@ -43,8 +47,10 @@ import numpy as np
 
 from repro.configs import get_config, list_archs
 from repro.core.bricks import decompose
-from repro.core.scheduler import populate_brick_bytes, schedule_split
+from repro.core.scheduler import (fleet_accelerators, populate_brick_bytes,
+                                  schedule_split)
 from repro.core.transport import PipeTransport, SocketTransport
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_params
 from repro.serving.disagg import DecodeWorker, PrefillWorker, \
     serve_disagg_inproc
@@ -119,6 +125,12 @@ def main(argv=None):
     ap.add_argument("--port", type=int, default=None,
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    if args.transport != "inproc" and jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"serve_disagg: --transport {args.transport} runs the decode "
+            "fleet in a second process, and a TPU chip belongs to one "
+            "process; use --transport inproc on TPU")
 
     if args.role == "decode":
         run_decode_fleet(args)
@@ -147,11 +159,14 @@ def main(argv=None):
     child = None
     if args.transport == "inproc":
         # degenerate single-host case: each fleet's engine on its OWN
-        # device ordinal (device:0 / device:1 — per-accelerator streams)
+        # device ordinal when there are two (device:0 / device:1), both
+        # on device:0 when one device is visible
+        pre_acc, dec_acc = fleet_accelerators(
+            args.transport, n_devices=jax.device_count())
         results, stats = serve_disagg_inproc(
             cfg, params, reqs,
-            prefill_kwargs=dict(backend="device:0", **ENGINE_KW),
-            decode_kwargs=dict(backend="device:1", **ENGINE_KW))
+            prefill_kwargs=dict(backend=pre_acc.backend, **ENGINE_KW),
+            decode_kwargs=dict(backend=dec_acc.backend, **ENGINE_KW))
     else:
         base_cmd = [sys.executable, "-m", "repro.launch.serve_disagg",
                     "--role", "decode", "--transport", args.transport,

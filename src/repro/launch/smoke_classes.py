@@ -208,8 +208,10 @@ def main(argv=None) -> int:
 
     import jax
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.steps import init_params
 
+    enable_compile_cache()
     cfg = get_config("llava-onevision-0.5b").reduced()
     params = init_params(jax.random.PRNGKey(0), cfg)
     if args.decode_cohort:

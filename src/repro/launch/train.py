@@ -15,6 +15,7 @@ import jax
 
 from repro.configs import get_config, list_archs
 from repro.data import multimodal_batch_iter
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.optimizer import OptConfig
 from repro.training.train_loop import TrainConfig, fit
 
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true",
                     help="use the full (pod-scale) config, not the reduced")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -101,7 +101,7 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, NamedTuple, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -397,10 +397,14 @@ class ServingEngine:
         self.cfg = cfg
         self.params = params
         # fused cohort-decode step (kernels/fused_decode): None resolves
-        # per the dispatch convention — compiled Pallas on real TPU only;
-        # off-TPU the composed path is the same numerics and faster than
-        # interpret mode.  True forces the fused step (tests/bench).
+        # per the dispatch convention — compiled Pallas on real TPU only
+        # (off-TPU it would run in interpret mode).  The two paths agree
+        # within a stated tolerance, not bit for bit; which is faster on
+        # the chip has not been measured.  True forces the fused step
+        # (tests/bench).  The resolution lands in cohort_path at the
+        # first cohort build: ("fused" | "composed", interpret?)
         self.use_fused = use_fused
+        self.cohort_path: Optional[Tuple[str, bool]] = None
         # paged decode pool: kv_blocks < n_slots*blocks_per_slot
         # oversubscribes slots against KV memory; admission grants per
         # request, per class (kv_block_budgets)
@@ -650,10 +654,11 @@ class ServingEngine:
 
         ``use_fused`` (engine flag) swaps the body for the fused
         Pallas step (kernels/fused_decode.cohort_step): in-VMEM weight
-        unpack + QKV/MLP GEMMs + single-position KV scatter, bit-equal
-        to this composed body.  Both flags (fused?, interpret?) resolve
-        HERE, at build time, outside the jit — the dispatch rule of
-        kernels/dispatch."""
+        unpack + QKV/MLP GEMMs + single-position KV scatter, within a
+        stated tolerance of this composed body.  Both flags (fused?,
+        interpret?) resolve HERE, at build time, outside the jit — the
+        dispatch rule of kernels/dispatch — and are recorded in
+        ``cohort_path``."""
         if bc not in self._cohort_cache:
             cfg = self.cfg
             paged = self.slots.paged
@@ -666,12 +671,18 @@ class ServingEngine:
             use_fused = self.use_fused
             if use_fused is None:
                 # default: fused only where compiled Pallas actually runs
-                # (real TPU, no force_ref override) — off-TPU interpret
-                # mode is the same numerics but strictly slower than the
-                # composed XLA path
-                use_fused = fused_supported(cfg) and not resolve_interpret()
+                # (real TPU, no force_ref override) on ONE device: Mosaic
+                # kernels are not partitioned automatically, and a
+                # decoder placed on a submesh of several devices runs its
+                # step on all of them.  Off-TPU, interpret mode would be
+                # far slower than the composed XLA path
+                devices = {d for leaf in jax.tree.leaves(self.slots.pool)
+                           for d in leaf.sharding.device_set}
+                use_fused = (fused_supported(cfg) and not resolve_interpret()
+                             and len(devices) == 1)
+            interp = bool(use_fused) and resolve_interpret(None)
+            self.cohort_path = ("fused" if use_fused else "composed", interp)
             if use_fused:
-                interp = resolve_interpret(None)
 
                 def fn(p, tokens, lengths, slot_ids, tables, pool):
                     return cohort_step(

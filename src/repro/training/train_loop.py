@@ -159,7 +159,6 @@ def build_ddp_train_step(cfg: ModelConfig, opt_cfg: OptConfig, mesh,
     "data"; the gradient psum goes through the int8 scheme when
     ``compress`` (the pjit path can't intercept its implicit reduction)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import psum_compressed
     from repro.models import model as M
 
@@ -177,8 +176,8 @@ def build_ddp_train_step(cfg: ModelConfig, opt_cfg: OptConfig, mesh,
                                              state=opt_state, cfg=opt_cfg)
         return params, opt_state, {"loss": loss, **om}
 
-    return shard_map(
+    return jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(), P("data")),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)

@@ -15,6 +15,9 @@ Covers the api_redesign acceptance criteria:
   re-lowering hook) change the substrate without changing the numbers.
 """
 import os
+import subprocess
+import sys
+import textwrap
 import threading
 
 import jax
@@ -33,6 +36,7 @@ from repro.core.power import PowerPolicy
 from repro.core.scheduler import (Accelerator, edge_accelerators,
                                   populate_brick_bytes, schedule)
 from repro.kernels import dispatch
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import init_params
 from repro.models.model import lm_forward
 from repro.serving.engine import Request, ServingEngine
@@ -49,7 +53,7 @@ def _submesh_accels():
     """Two submesh accelerators over the test container's single device —
     enough to drive the SubmeshBackend lowering (NamedSharding binds +
     SubmeshPipe edges); the 8-device split runs in scripts/check.sh."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     return [
         Accelerator("enc", TPU_V5E, static_only=True, dynamic_ok=False,
                     mesh=mesh, backend="submesh"),
@@ -323,3 +327,50 @@ def test_engine_applies_demotion_and_restores(vlm):
         ex.pmu.level = 1.0                               # charge recovers
         eng.step()
         assert eng.plan.backend_of("projector").name == "device"
+
+
+SPLIT_DECODER = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from repro.configs import get_config
+    from repro.core.bricks import decompose
+    from repro.core.scheduler import make_virtual_accelerators
+    from repro.kernels import dispatch
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import init_params
+    from repro.serving.engine import Request, ServingEngine
+
+    cfg = get_config("llava-onevision-0.5b").reduced()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    enc, dec = make_virtual_accelerators(make_mesh((1, 4), ("data", "model")),
+                                         fractions=(0.25, 0.75))
+    placement = {b.name: (enc.name if b.static_shape else dec.name)
+                 for b in decompose(cfg).bricks}
+    dispatch.on_tpu = lambda: True     # the default resolution a TPU sees
+    with ServingEngine(cfg, params, n_slots=2, max_len=128,
+                       placement=placement, accels=[enc, dec]) as eng:
+        eng.submit(Request(rid=0, tokens=np.arange(12, dtype=np.int32) + 3,
+                           max_new_tokens=3,
+                           vision_feats=np.zeros((1, cfg.vision_tokens,
+                                                  cfg.vision_feat_dim),
+                                                 np.float32)))
+        done = eng.run()
+        assert done[0].error is None, done[0].error
+        assert eng.cohort_path == ("composed", False), eng.cohort_path
+    print("SPLIT_OK")
+""")
+
+
+def test_decoder_on_several_devices_takes_composed_step():
+    """A decoder brick placed on a submesh of 3 devices decodes over all
+    of them, where a Mosaic kernel cannot be partitioned: the engine's
+    default resolution must pick the composed step there, even on a TPU
+    (on_tpu forced; the composed step then runs on the CPU as is)."""
+    proc = subprocess.run([sys.executable, "-c", SPLIT_DECODER],
+                          capture_output=True, text=True, timeout=600,
+                          env=dict(os.environ, PYTHONPATH="src"),
+                          cwd=os.path.join(os.path.dirname(__file__), ".."))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "SPLIT_OK" in proc.stdout

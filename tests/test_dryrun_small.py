@@ -25,8 +25,9 @@ SCRIPT = textwrap.dedent("""
     import repro.launch.dryrun  # noqa
     from repro.analysis import roofline as rl
 
+    from repro.launch.mesh import make_mesh
     cfg = get_config("{arch}").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cell = ShapeCell("t", "{kind}", {seq}, {batch})
     with mesh:
         lowered, compiled = dr.lower_cell(cfg, cell, mesh)

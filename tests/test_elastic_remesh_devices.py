@@ -20,6 +20,7 @@ SCRIPT = textwrap.dedent("""
     from repro.distributed import checkpoint as ck
     from repro.distributed import sharding as sh
     from repro.distributed.fault_tolerance import plan_remesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import init_params
     from repro.training.optimizer import OptConfig, init_opt
     from repro.training.train_loop import build_accum_train_step
@@ -31,7 +32,7 @@ SCRIPT = textwrap.dedent("""
     data = multimodal_batch_iter(cfg, global_batch=8, seq_len=64)
 
     # phase 1: 8 devices as (2 data, 4 model)
-    mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh1 = make_mesh((2, 4), ("data", "model"))
     params = init_params(jax.random.PRNGKey(0), cfg)
     pspecs = sh.tree_param_specs(mesh1, params)
     params = jax.device_put(params, sh.tree_shardings(mesh1, pspecs))
@@ -49,7 +50,7 @@ SCRIPT = textwrap.dedent("""
     # preserves the model axis and shrinks DP
     plan = plan_remesh(alive_workers=[0], devices_per_worker=4, model_axis=4)
     assert plan.shape == (1, 4), plan.shape
-    mesh2 = jax.make_mesh(plan.shape, plan.axes)
+    mesh2 = make_mesh(plan.shape, plan.axes)
     like = {"params": params, "opt": opt}
     shards = {"params": sh.tree_shardings(
                   mesh2, sh.tree_param_specs(mesh2, params)),

@@ -14,6 +14,7 @@ from repro.distributed import checkpoint as ck
 from repro.distributed.fault_tolerance import (HeartbeatMonitor, RemeshPlan,
                                                StragglerMitigator,
                                                plan_remesh)
+from repro.launch.mesh import make_mesh
 from repro.training.optimizer import OptConfig
 from repro.training.train_loop import TrainConfig, fit
 
@@ -90,7 +91,7 @@ def test_kill_restore_continue_elastic():
 def test_restore_with_resharding(key):
     """restore() binds new shardings — the reshard-on-load contract."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     with tempfile.TemporaryDirectory() as d:
         ck.save(d, 1, tree)

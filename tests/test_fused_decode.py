@@ -1,19 +1,20 @@
-"""Fused low-bit Pallas cohort-decode kernels (PR 10).
+"""Fused low-bit Pallas cohort-decode kernels.
 
 The contract battery for ``kernels/fused_decode``:
 
-* **kernel == oracle, per kernel** — fused QKV / fused MLP match the
-  composed dequantize->einsum chains bit for bit across dense/q4/q8
-  weights; the KV row scatter matches the engine's
+* **kernel == oracle, per kernel** — fused QKV matches the composed
+  dequantize->einsum chain bit for bit across dense/q4/q8 weights; fused
+  MLP (tiled over ``d_ff``, activation in f32) matches it within
+  ``TOL`` of the output's scale; the KV row scatter matches the engine's
   ``.at[...].set(mode="drop")`` pass, and sentinel rows write NOTHING
   (the aliased pool block keeps its prior bits);
-* **fused cohort step == composed oracle, bit-identical** — the tentpole
-  acceptance bar: ``cohort_step(use_fused=True)`` equals
-  ``ref_cohort_step`` (today's three engine dispatches: gather ->
-  ``lm_decode_step`` -> scatter) on logits AND pools, across cohort
-  buckets x bit-widths, eager and under ``jax.jit`` (the engine always
-  jits), plus a property sweep over random lengths / block tables /
-  sentinel rows;
+* **fused cohort step ~= composed oracle** — ``cohort_step(use_fused=
+  True)`` agrees with ``ref_cohort_step`` (the engine's three composed
+  dispatches: gather -> ``lm_decode_step`` -> scatter) within ``TOL`` on
+  logits and on the newly written K/V rows, and leaves every other pool
+  cell bit-identical, across cohort buckets x bit-widths, eager and
+  under ``jax.jit`` (the engine always jits), plus a property sweep over
+  random lengths / block tables / sentinel rows;
 * **engine wiring** — ``ServingEngine(use_fused=True)`` emits greedy
   tokens identical to the composed engine; unsupported archs (hybrid
   SSM) refuse the fused path;
@@ -60,6 +61,25 @@ def lm_q8(lm):
     return cfg, quantize_tree(params, PROFILES["dec-q8"])
 
 
+# Fused and composed steps are both bf16 paths that round at different
+# points: the fused MLP evaluates its activation in f32 and rounds once,
+# and sums the down projection tile by tile.  Measured gaps at these
+# widths are 1.7e-2..4.1e-2 on logits of max magnitude ~3, the same size
+# as the composed step's own gap to a float32 reference (3.5e-2), so the
+# bound is relative to the compared tensor's largest magnitude.
+TOL = 2.5e-2
+
+
+def _assert_close(got, want, what=""):
+    g = np.asarray(got, np.float32)
+    w = np.asarray(want, np.float32)
+    assert g.shape == w.shape and got.dtype == want.dtype
+    # initial=0: a cohort of sentinel rows writes no K/V cell at all
+    diff = float(np.max(np.abs(g - w), initial=0.0))
+    bound = TOL * max(1.0, float(np.max(np.abs(w), initial=0.0)))
+    assert diff <= bound, f"{what} max|diff| {diff:.3e} > {bound:.3e}"
+
+
 def _maybe_q(w, label):
     return w if label == "dense" else quantize(
         w, parse_label(label)[0])
@@ -91,8 +111,14 @@ def test_fused_qkv_matches_composed(key, label, bias):
 
 @pytest.mark.parametrize("label", ["dense", "q4f16-g32"])
 @pytest.mark.parametrize("act", ["swiglu", "gelu"])
-def test_fused_mlp_matches_composed(key, label, act):
-    D, F, bc = 64, 128, 3
+def test_fused_mlp_matches_composed(key, label, act, monkeypatch):
+    """Four d_ff tiles of 128 (the VMEM tile budget shrunk to fit one
+    gated tile): exercises the accumulating grid and, for packed column
+    matrices, the pre-tiled operand layout."""
+    from repro.kernels.fused_decode import kernel as K
+    D, F, bc = 64, 512, 3
+    monkeypatch.setattr(K, "_TILE_BUDGET", 2 * 3 * D * 2 * 128)
+    assert F // K.ff_tile(F, D, 3 if act == "swiglu" else 2, 2) == 4
     ks = jax.random.split(key, 4)
     h = jax.random.normal(ks[0], (bc, 1, D), jnp.bfloat16)
     w_up = _maybe_q(jax.random.normal(ks[1], (D, F), jnp.bfloat16), label)
@@ -103,7 +129,7 @@ def test_fused_mlp_matches_composed(key, label, act):
                           label)
     got = fused_mlp(h, w_up, w_down, w_gate, act=act, interpret=True)
     want = ref_fused_mlp(h, w_up, w_down, w_gate, act=act)
-    assert got.dtype == want.dtype and bool(jnp.array_equal(got, want))
+    _assert_close(got, want, "mlp")
 
 
 def test_kv_scatter_matches_and_sentinel_writes_nothing(key):
@@ -130,7 +156,7 @@ def test_kv_scatter_matches_and_sentinel_writes_nothing(key):
 
 
 # ---------------------------------------------------------------------------
-# the tentpole bar: fused cohort step == composed oracle, bit for bit
+# fused cohort step against the composed oracle
 # ---------------------------------------------------------------------------
 
 def _cohort_state(cfg, bc, *, nb=16, bs=4, W=6, seed=7, sentinel=True,
@@ -152,7 +178,7 @@ def _cohort_state(cfg, bc, *, nb=16, bs=4, W=6, seed=7, sentinel=True,
     return tokens, lengths, slot_ids, tables, pool, bs
 
 
-def _assert_bit_identical(cfg, params, bc, *, jit=False, **state_kw):
+def _assert_matches_composed(cfg, params, bc, *, jit=False, **state_kw):
     tokens, lengths, slot_ids, tables, pool, bs = _cohort_state(
         cfg, bc, **state_kw)
     paged = paged_positions(cfg)
@@ -165,35 +191,46 @@ def _assert_bit_identical(cfg, params, bc, *, jit=False, **state_kw):
     args = (tokens, lengths, slot_ids, tables, pool)
     lr, pr = ref_fn(*args)
     lf, pf = fused_fn(*args)
-    assert bool(jnp.array_equal(lr, lf)), (
-        f"bc={bc}: fused logits diverged, maxdiff "
-        f"{float(jnp.max(jnp.abs(lr.astype(jnp.float32) - lf.astype(jnp.float32)))):.3e}")
+    _assert_close(lf, lr, f"bc={bc} logits")
+    # the written cells (one per live row and layer) agree within TOL;
+    # every other pool cell, sentinel rows' targets included, is
+    # bit-identical
+    nb = pool[0][0].shape[1]
+    blk = np.asarray(jnp.take_along_axis(
+        tables, (lengths // bs)[:, None], axis=1)[:, 0])
+    off = np.asarray(lengths % bs)
+    written = np.zeros(pool[0][0].shape[1:3], bool)
+    for b, o in zip(blk, off):
+        if b < nb:
+            written[b, o] = True
     for a, b in zip(jax.tree.leaves(pr), jax.tree.leaves(pf)):
-        assert bool(jnp.array_equal(a, b)), f"bc={bc}: pools diverged"
+        _assert_close(b[:, written], a[:, written], f"bc={bc} new K/V")
+        assert bool(jnp.array_equal(a[:, ~written], b[:, ~written])), (
+            f"bc={bc}: pool cells outside the written rows changed")
 
 
 @pytest.mark.parametrize("bc", [1, 2, 4])
 def test_cohort_step_bit_identical_dense(lm, bc):
     cfg, params = lm
-    _assert_bit_identical(cfg, params, bc)
+    _assert_matches_composed(cfg, params, bc)
 
 
 @pytest.mark.parametrize("bc", [1, 2, 4])
 def test_cohort_step_bit_identical_q4(lm_q4, bc):
     cfg, params = lm_q4
-    _assert_bit_identical(cfg, params, bc)
+    _assert_matches_composed(cfg, params, bc)
 
 
 def test_cohort_step_bit_identical_q8(lm_q8):
     cfg, params = lm_q8
-    _assert_bit_identical(cfg, params, 2)
+    _assert_matches_composed(cfg, params, 2)
 
 
 def test_cohort_step_bit_identical_under_jit(lm_q4):
-    """The engine always jits its cohort fn — equality must survive
+    """The engine always jits its cohort fn — agreement must survive
     compilation, not just eager interpret mode."""
     cfg, params = lm_q4
-    _assert_bit_identical(cfg, params, 2, jit=True)
+    _assert_matches_composed(cfg, params, 2, jit=True)
 
 
 @settings(max_examples=6, deadline=None)
@@ -203,7 +240,7 @@ def test_cohort_step_bit_identical_under_jit(lm_q4):
 def test_cohort_step_property_lengths_and_tables(lm_q4, data, sentinel):
     """Random per-row lengths (any block offset, including block
     boundaries) and shuffled disjoint block tables, with 0-2 rows
-    replaced by sentinels: fused stays bit-identical to composed."""
+    replaced by sentinels: fused stays within TOL of composed."""
     cfg, params = lm_q4
     bc, W, nb, bs = 2, 6, 16, 4
     lengths = jnp.asarray([d[0] for d in data], jnp.int32)
@@ -212,7 +249,7 @@ def test_cohort_step_property_lengths_and_tables(lm_q4, data, sentinel):
     for i in range(min(sentinel, bc)):
         tables = tables.at[i].set(nb)
         lengths = lengths.at[i].set(0)
-    _assert_bit_identical(cfg, params, bc, nb=nb, bs=bs, W=W,
+    _assert_matches_composed(cfg, params, bc, nb=nb, bs=bs, W=W,
                           sentinel=False, lengths=lengths, tables=tables)
 
 
@@ -230,7 +267,8 @@ def test_unsupported_arch_refuses_fused(lm):
 
 
 def test_engine_fused_matches_composed_tokens(lm):
-    """End to end through ServingEngine: identical greedy tokens."""
+    """End to end through ServingEngine: identical greedy tokens (the
+    logit gaps stay far below this model's top-2 margins)."""
     from repro.serving.engine import Request, ServingEngine
     cfg = get_config("stablelm-1.6b").reduced()
     params = init_params(jax.random.PRNGKey(0), cfg)
