@@ -1,0 +1,7 @@
+"""Output tokens stamped inside the window over the window's length."""
+
+
+def read(run):
+    if run.window_s <= 0:
+        return None
+    return run.tokens_in_window() / run.window_s
