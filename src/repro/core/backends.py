@@ -87,7 +87,12 @@ def brick_executable(brick: Brick, cfg, mode: str = "auto") -> Callable:
         fn = _JIT_CACHE.get(key)
         if fn is not None:
             return fn
-        jitted = jax.jit(lambda p, ctx, _b=brick: _b.apply(p, cfg, ctx))
+        def apply(p, ctx, _b=brick):
+            return _b.apply(p, cfg, ctx)
+
+        # a stable program name in the trace: jit_brick_<name>
+        apply.__name__ = apply.__qualname__ = f"brick_{brick.name}"
+        jitted = jax.jit(apply)
         if mode == "ref":
             def fn(p, ctx, _j=jitted):
                 with dispatch.force_ref():

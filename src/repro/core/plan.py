@@ -44,6 +44,7 @@ Paper-term → API mapping:
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -330,6 +331,13 @@ class ExecutionPlan:
         return view[None, :n], ring, s
 
     # -- TABM edge, split for the engine's producer/consumer decoupling -----
+    def _span(self, name: str, brick: str, phase: str, tokens: int = 0):
+        """A probe span (a profiler annotation too), or nothing without
+        a probe."""
+        if self.probe is None:
+            return contextlib.nullcontext()
+        return self.probe.span(name, brick, phase, tokens)
+
     def _tabm_ring(self, slot_class: Optional[str]):
         """Resolve the ring a TABM operation targets: the single ring, or
         the named class ring of a class-partitioned pool."""
@@ -426,8 +434,9 @@ class ExecutionPlan:
             if n > ring.max_tokens:
                 raise PlanError(f"{n} vision tokens > slot capacity "
                                 f"{ring.max_tokens} of the target ring")
-        slots = ring.acquire_write_many(len(feats), block=block,
-                                        timeout=timeout)
+        with self._span("tabm.acquire", "tabm", "acquire"):
+            slots = ring.acquire_write_many(len(feats), block=block,
+                                            timeout=timeout)
         if slots is None:
             return None
         try:
@@ -446,19 +455,17 @@ class ExecutionPlan:
             for step in self.steps[: self._tabm_producer + 1]:
                 transient = not step.backend.resident
                 dev_params = self._load(step)
-                t0 = time.perf_counter()
-                ctx = self._gather(step, env, env_src)
-                out = step.fn(dev_params, ctx)
-                if transient:
-                    # deliberate residency trace point (see run())
-                    out = jax.block_until_ready(out)  # replint: disable=host-sync
-                    step.backend.unload(dev_params)
+                with self._span(f"tabm.stage.{step.brick.name}",
+                                step.brick.name, "stage",
+                                tokens=len(feats) * slab):
+                    ctx = self._gather(step, env, env_src)
+                    out = step.fn(dev_params, ctx)
+                    if transient:
+                        # deliberate residency trace point (see run())
+                        out = jax.block_until_ready(out)  # replint: disable=host-sync
+                        step.backend.unload(dev_params)
                 env[step.brick.out_port.name] = out
                 env_src[step.brick.out_port.name] = step.accel
-                if self.probe is not None:
-                    self.probe.record(step.brick.name, "stage",
-                                      time.perf_counter() - t0,
-                                      tokens=len(feats) * slab)
             if out.shape[0] != len(feats):
                 raise PlanError(f"projector returned batch {out.shape[0]} "
                                 f"for a {len(feats)}-request microbatch")
@@ -472,7 +479,8 @@ class ExecutionPlan:
                     f"({slab} -> {out.shape[1]}); produce_many requires "
                     f"token-count-preserving staging bricks")
             v = out if self._tabm_transfer is None else self._tabm_transfer(out)
-            ring.commit_many(slots, v, lengths)
+            with self._span("tabm.commit", "tabm", "commit"):
+                ring.commit_many(slots, v, lengths)
         except Exception:
             ring.abort_many(slots)
             raise

@@ -49,4 +49,5 @@ def cache_row_update_pallas(cache, row, index, *, interpret: bool = False):
         input_output_aliases={2: 0},       # cache (after the prefetch
                                            # scalar and the row) aliases out
         interpret=interpret,
+        name="cache_row_update",
     )(index, row, cache)

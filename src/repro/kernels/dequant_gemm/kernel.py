@@ -122,4 +122,5 @@ def dequant_gemm_pallas(x, codes, scales, bias=None, *, bits: int,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=cp,
         interpret=interpret,
+        name="dequant_gemm",
     )(*args)

@@ -91,4 +91,5 @@ def flash_attention_pallas(q, k, v, *, kv_heads: int, causal: bool = True,
                         pltpu.VMEM((bq, hd), jnp.float32)],
         compiler_params=cp,
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -192,6 +192,7 @@ def fused_qkv_pallas(h, wq, wk, wv,
         out_shape=out_shapes,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="fused_qkv",
     )(*operands)
     return tuple(o.reshape(bc, 1, n, hd) for o, (n, hd) in zip(outs, heads))
 
@@ -307,6 +308,7 @@ def fused_mlp_pallas(h, w_up, w_down, w_gate=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_mlp",
     )(*operands)
     return out.reshape(h.shape)
 
@@ -358,4 +360,5 @@ def kv_row_scatter_pallas(blk, off, k_rows, v_rows, k_pool, v_pool, *,
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="kv_row_scatter",
     )(blk, off, k_rows, v_rows, k_pool, v_pool)

@@ -97,4 +97,5 @@ def linear_attention_pallas(q, k, v, *, chunk: int = 256,
                         pltpu.VMEM((1, hd), jnp.float32)],
         compiler_params=cp,
         interpret=interpret,
+        name="linear_attention",
     )(q, k, v)

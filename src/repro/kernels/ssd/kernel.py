@@ -107,4 +107,5 @@ def ssd_pallas(x, dt, A, Bm, Cm, *, chunk: int = 256,
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         compiler_params=cp,
         interpret=interpret,
+        name="ssd",
     )(x, dt, A.reshape(H, 1), Bm, Cm)

@@ -90,7 +90,15 @@ holder releases.
 Metrics mirror the paper's evaluation: tokens/s, end-to-end latency
 (submit -> finish), modeled energy, memory (pool + weights).  ``trace``
 records the producer/consumer interleaving ((event, rid, t) tuples) —
-the overlap evidence the async tests assert on.
+the overlap evidence the async tests assert on.  ``probe`` holds the
+program's spans, each also a profiler annotation of the same name:
+``serve.submit``, ``serve.admit`` (one admission round), ``serve.park``
+(an idle wait in it), ``serve.prefill`` (one group), ``serve.decode``
+split into ``.launch`` / ``.wait`` / ``.sample``, ``tabm.wait_ready``,
+the plan's ``tabm.acquire`` / ``tabm.stage.<brick>`` / ``tabm.commit``,
+and ``jit.trace`` / ``jit.compile`` (see telemetry/probes.py).  Each
+request carries its lifecycle on ``time.monotonic()``: ``submit_t``,
+``staged_t``, ``admit_t``, ``first_token_mt``, ``finish_mt``.
 """
 from __future__ import annotations
 
@@ -118,9 +126,12 @@ from repro.serving.kv_cache import PagedKVCache, SlotCache, bucket_length
 from repro.serving.sampling import sample
 from repro.telemetry.calibration import CostCalibration
 from repro.telemetry.ledger import Ledger
-from repro.telemetry.probes import WallProbe
+from repro.telemetry.probes import WallProbe, jit_counts, watch_jit
 
 EOS_ID = 1
+# wall clock minus monotonic clock, read once: maps the monotonic
+# lifecycle stamps onto calendar time (Request.first_token_t, finish_t)
+_WALL_MINUS_MONO = time.time() - time.monotonic()
 
 
 class TraceEvent(NamedTuple):
@@ -147,9 +158,14 @@ class Request:
     n_images: int = 1                      # images the vision feats cover
     max_new_tokens: int = 32
     temperature: float = 0.0
-    submit_t: float = field(default_factory=time.time)
-    first_token_t: Optional[float] = None
-    finish_t: Optional[float] = None
+    # lifecycle stamps, all time.monotonic(): handed to the engine
+    # (submit), slab committed or nothing to stage (staged), its prefill
+    # group began (admit), first token picked, finished or failed
+    submit_t: Optional[float] = None
+    staged_t: Optional[float] = None
+    admit_t: Optional[float] = None
+    first_token_mt: Optional[float] = None
+    finish_mt: Optional[float] = None
     out_tokens: List[int] = field(default_factory=list)
     slot: Optional[int] = None                 # KV-cache slot once admitted
     tabm_slot: Optional[int] = None            # class-ring slot once staged
@@ -180,9 +196,28 @@ class Request:
         desynchronize."""
         return self._staged_ev.is_set()
 
+    def _mark_staged(self):
+        if self.staged_t is None:
+            self.staged_t = time.monotonic()
+        self._staged_ev.set()
+
+    @property
+    def first_token_t(self) -> Optional[float]:
+        """Wall-clock (``time.time()``) time of the first token."""
+        return None if self.first_token_mt is None \
+            else self.first_token_mt + _WALL_MINUS_MONO
+
+    @property
+    def finish_t(self) -> Optional[float]:
+        """Wall-clock (``time.time()``) time the request finished."""
+        return None if self.finish_mt is None \
+            else self.finish_mt + _WALL_MINUS_MONO
+
     @property
     def e2e_latency(self) -> Optional[float]:
-        return None if self.finish_t is None else self.finish_t - self.submit_t
+        if self.finish_mt is None or self.submit_t is None:
+            return None
+        return self.finish_mt - self.submit_t
 
 
 @dataclass
@@ -192,10 +227,13 @@ class EngineStats:
     steps: int = 0
     finished: int = 0
     failed: int = 0
-    start_t: float = field(default_factory=time.time)
+    start_t: Optional[float] = None      # first decode step (monotonic)
 
     def tokens_per_s(self) -> float:
-        dt = time.time() - self.start_t
+        """Decoded tokens per second since the first decode step."""
+        if self.start_t is None:
+            return 0.0
+        dt = time.monotonic() - self.start_t
         return self.decoded_tokens / dt if dt > 0 else 0.0
 
 
@@ -340,7 +378,7 @@ class StagingWorker:
             with self._lock:
                 self._in_flight[slot_class] -= len(batch)
             for req in batch:
-                req._staged_ev.set()            # marks staged
+                req._mark_staged()
 
     def _restage_isolated(self, slot_class: Optional[str],
                           batch: List[Request]):
@@ -440,6 +478,10 @@ class ServingEngine:
         # measured ledger) lets admission price KV budgets from
         # observation (see _kv_energy_pressure)
         self.probe = WallProbe()
+        # jit traces, compiles and cache loads, counted for the process
+        # and recorded as jit.* spans into this probe (jit_counts())
+        watch_jit(self.probe)
+        self._jit_base = jit_counts()
         self.calibration = calibration
         self._kv_pressure: Optional[float] = None
         # class-partitioned TABM pool between encoder and decoder bricks
@@ -504,8 +546,13 @@ class ServingEngine:
     def submit(self, req: Request):
         if self._closed:
             raise EngineClosed("engine already shut down")
+        with self.probe.span("serve.submit", "engine", "submit"):
+            req.submit_t = time.monotonic()
+            self._submit(req)
+
+    def _submit(self, req: Request):
         if self.tabm is None or req.vision_feats is None:
-            req._staged_ev.set()           # text-only: nothing to commit
+            req._mark_staged()             # text-only: nothing to commit
         elif req.slot_class is None:
             # classify from the vision spec (token count x image count) —
             # the request is charged against exactly this class's ring and
@@ -522,7 +569,7 @@ class ServingEngine:
             req._share_key = key
             owner = self._stage_keys.get(key)
             if (owner is not None and owner.error is None
-                    and owner.finish_t is None):
+                    and owner.finish_mt is None):
                 req.share_of = owner
                 owner.sharers.append(req)
             else:
@@ -596,6 +643,7 @@ class ServingEngine:
             bs = self.slots.block_size
             decode_len = -(-bucket // bs) * bs
 
+            @jax.named_scope("serve_prefill")
             def fn(p, tokens, vision_embeds, last_idx):
                 """Right-padded bucket prefill; logits read at the true
                 prompt end (last_idx-1); pad positions stay in the cache
@@ -621,6 +669,8 @@ class ServingEngine:
                 logits = M._head(p, cfg, x_last)
                 return logits[:, 0], {"layers": caches}
 
+            # the compiled module is named after the function (jit_<name>)
+            fn.__name__ = fn.__qualname__ = f"serve_prefill_b{bucket}"
             self._prefill_cache[bucket] = jax.jit(fn)
         return self._prefill_cache[bucket]
 
@@ -682,17 +732,21 @@ class ServingEngine:
                              and len(devices) == 1)
             interp = bool(use_fused) and resolve_interpret(None)
             self.cohort_path = ("fused" if use_fused else "composed", interp)
+            name = f"serve_cohort_b{bc}"       # jit_<name> in the trace
             if use_fused:
 
+                @jax.named_scope("serve_decode")
                 def fn(p, tokens, lengths, slot_ids, tables, pool):
                     return cohort_step(
                         p, cfg, tokens, lengths, slot_ids, tables, pool,
                         block_size=bs, paged=paged, use_fused=True,
                         interpret=interp)
 
+                fn.__name__ = fn.__qualname__ = name
                 self._cohort_cache[bc] = jax.jit(fn, donate_argnums=(5,))
                 return self._cohort_cache[bc]
 
+            @jax.named_scope("serve_decode")
             def fn(p, tokens, lengths, slot_ids, tables, pool):
                 layers = []
                 for pos, is_paged in enumerate(paged):
@@ -733,6 +787,7 @@ class ServingEngine:
                             pool[pos], new["layers"][pos]))
                 return logits, tuple(out)
 
+            fn.__name__ = fn.__qualname__ = name
             self._cohort_cache[bc] = jax.jit(fn, donate_argnums=(5,))
         return self._cohort_cache[bc]
 
@@ -774,14 +829,14 @@ class ServingEngine:
                     slot_class=req.slot_class)
             except Exception as e:             # surface on the owning request
                 req.error = e
-                req._staged_ev.set()            # marks staged
+                req._mark_staged()
                 self._trace_event("stage_error", req.rid)
                 continue
             if slot is None:                   # class FULL -> stall the class
                 stalled.add(req.slot_class)
                 continue
             req.tabm_slot = slot
-            req._staged_ev.set()           # marks staged
+            req._mark_staged()
             self._trace_event("stage_commit", req.rid)
 
     def _class_stage_batch(self, slot_class: Optional[str]) -> int:
@@ -872,8 +927,10 @@ class ServingEngine:
         # which the worker sets strictly after commit — but this is the
         # formal consumer-side gate (and the blocking point if admission
         # ever runs ahead of the staged flag)
-        if not self.plan.wait_ready(req.tabm_slot, timeout=30.0,
-                                    slot_class=req.slot_class):
+        with self.probe.span("tabm.wait_ready", "tabm", "wait_ready"):
+            ready = self.plan.wait_ready(req.tabm_slot, timeout=30.0,
+                                         slot_class=req.slot_class)
+        if not ready:
             raise TABMError(
                 f"slot {req.tabm_slot} ({req.slot_class}) did not become "
                 f"READY (aborted, ring closed, or timed out)")
@@ -901,14 +958,14 @@ class ServingEngine:
                 self._stage_keys.get(owner._share_key) is owner:
             self._stage_keys.pop(owner._share_key)
         for s in owner.sharers:
-            if (s.error is not None or s.finish_t is not None
+            if (s.error is not None or s.finish_mt is not None
                     or s.share_of is not owner):
                 continue
             if self.plan.addref(slot, owner._tabm_gen,
                                 slot_class=owner.slot_class):
                 s.tabm_slot = slot
                 s._tabm_gen = owner._tabm_gen
-                s._staged_ev.set()         # admissible, no staging needed
+                s._mark_staged()           # admissible, no staging needed
                 self._trace_event("stage_share", s.rid)
             else:
                 s.share_of = None          # stage privately instead
@@ -927,7 +984,8 @@ class ServingEngine:
 
     def _fail(self, req: Request):
         self._unshare(req)
-        req.finish_t = req.finish_t or time.time()
+        if req.finish_mt is None:
+            req.finish_mt = time.monotonic()
         self.stats.failed += 1
         self._trace_event("failed", req.rid)
         self.done.append(req)
@@ -1020,7 +1078,10 @@ class ServingEngine:
         batch-level in practice — the per-request inputs (bucketed int
         tokens, validated slab views) cannot individually fail a
         compiled call."""
-        t0 = time.perf_counter()
+        # the group's prefill span; a failed group is not measured
+        span = self.probe.span("serve.prefill", "decoder", "prefill").start()
+        for req in group:
+            req.admit_t = span.t0
         taken: List[int] = []
         try:
             for req in group:
@@ -1083,6 +1144,7 @@ class ServingEngine:
                 self._fail(req)
             for slot in taken:
                 self.slots.release(slot)
+            span.end(keep=False)
             return
         self.slots.insert_many(taken, cache, [int(n) for n in lens])
         for b, (slot, req) in enumerate(zip(taken, group)):
@@ -1093,15 +1155,20 @@ class ServingEngine:
             # first token from this request's row of the prefill logits
             tok = self._pick(logits[b:b + 1], req)
             req.out_tokens.append(int(tok[0]))
-            req.first_token_t = time.time()
+            req.first_token_mt = time.monotonic()
         if len(group) > 1:                     # the acceptance evidence
             self._trace_event("prefill_batch", len(group))
         # measured prefill span: ends past insert_many and the first-token
         # reads, so device work is complete — true wall time of the group
-        self.probe.record("decoder", "prefill", time.perf_counter() - t0,
-                          tokens=int(lens.sum()))
+        span.tokens = int(lens.sum())
+        span.end()
 
     def _admit(self):
+        """One admission round (the ``serve.admit`` span)."""
+        with self.probe.span("serve.admit", "engine", "admit"):
+            self._admit_round()
+
+    def _admit_round(self):
         state, knobs, _ = self.executor.current()
         self._apply_backend_knobs(knobs)
         power_ok = (knobs.admission_rate > 0
@@ -1225,14 +1292,16 @@ class ServingEngine:
                                if r.error is None and r.stage_submitted
                                and not r.staged), None)
             if waiter is not None:
-                waiter._staged_ev.wait(0.05)
+                with self.probe.span("serve.park", "engine", "park"):
+                    waiter._staged_ev.wait(0.05)
             elif not any(r.staged and r.error is None for r in self.queue):
                 # nothing live, nothing admissible, nothing being staged —
                 # every queued request is power- or class-depth-gated.
                 # Breathe instead of hot-spinning the step loop at full
                 # CPU (which would burn the very battery the throttle is
                 # conserving) until charge recovers.
-                time.sleep(0.005)
+                with self.probe.span("serve.park", "engine", "park"):
+                    time.sleep(0.005)
 
     def _pick(self, logits, req: Request):
         if req.temperature == 0.0:
@@ -1268,13 +1337,23 @@ class ServingEngine:
             tokens[b, 0] = req.out_tokens[-1]
             lengths[b] = self.slots.lengths[slot]
             slot_ids[b] = slot
-        t0 = time.perf_counter()
+        # measured decode span for the telemetry ledger, in three parts
+        # that tile it: launch (dispatch of the cohort step), wait (the
+        # first row's sampling read, which returns once the device step
+        # is done) and sample (the other rows).  The per-token reads
+        # sync, so the span is true wall time of one cohort step (host
+        # clocks only — replint-clean)
+        span = self.probe.span("serve.decode", "decoder", "decode",
+                               tokens=len(cohort)).start()
+        span.part("serve.decode.launch", "decode.launch")
+        if self.stats.start_t is None:
+            self.stats.start_t = span.t0
         logits, self.slots.pool = self._cohort_fn(bc)(
             self.params, jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(slot_ids), jnp.asarray(tables), self.slots.pool)
         self.stats.steps += 1
-        self._trace_event("decode_step", self.stats.steps)
         self._trace_event("decode_cohort", len(cohort))
+        span.part("serve.decode.wait", "decode.wait")
 
         finished = []
         for b, slot in enumerate(cohort):
@@ -1289,13 +1368,11 @@ class ServingEngine:
             over_len = self.slots.lengths[slot] + 1 >= self.max_len
             if (t == EOS_ID or len(req.out_tokens) >= req.max_new_tokens
                     or over_len):
-                req.finish_t = time.time()
+                req.finish_mt = time.monotonic()
                 finished.append(slot)
-        # measured decode span for the telemetry ledger: the per-token
-        # sampling reads above already synced, so this is true wall time
-        # of one cohort step (host clocks only — replint-clean)
-        self.probe.record("decoder", "decode", time.perf_counter() - t0,
-                          tokens=len(cohort))
+            if b == 0:
+                span.part("serve.decode.sample", "decode.sample")
+        span.end()
         for slot in finished:
             req = self.live.pop(slot)
             self.done.append(req)
@@ -1389,7 +1466,9 @@ class ServingEngine:
                       slot_class=msg.slot_class)
         req.slot = slot
         req.out_tokens.append(int(msg.first_token))
-        req.first_token_t = time.time()
+        # its life on this engine begins here, with its first token
+        req.submit_t = req.staged_t = req.admit_t = req.first_token_mt = \
+            time.monotonic()
         req._staged_ev.set()
         self.live[slot] = req
         self.stats.prefills += 1
@@ -1423,6 +1502,13 @@ class ServingEngine:
                     break
             self._kv_pressure = press
         return self._kv_pressure
+
+    def jit_counts(self) -> Dict[str, int]:
+        """jaxpr traces, backend compiles and persistent-cache loads in
+        this process since the engine was built (``jit.*`` spans in
+        :attr:`probe`)."""
+        now = jit_counts()
+        return {k: now[k] - self._jit_base[k] for k in now}
 
     def measured_ledger(self) -> Ledger:
         """The dynamic (probe-fed) telemetry ledger of this engine run:

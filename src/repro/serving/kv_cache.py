@@ -47,7 +47,7 @@ from repro.models import model as M
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _insert_slots(pool_leaf, batch_leaf, slots: jnp.ndarray):
+def serve_kv_insert_slots(pool_leaf, batch_leaf, slots: jnp.ndarray):
     """Write a batch-K cache leaf (L, K, ...) into rows `slots` of the
     (L, B, ...) pools — ONE strided scatter per leaf, donated in place,
     so a grouped batch-B prefill lands in B slots in a single op instead
@@ -56,12 +56,12 @@ def _insert_slots(pool_leaf, batch_leaf, slots: jnp.ndarray):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
-def _insert_blocks(pool_leaf, batch_leaf, block_ids: jnp.ndarray,
-                   block_size: int):
-    """Paged twin of :func:`_insert_slots`: a batch-K prefilled attention
-    leaf (L, K, S, ...) with block-aligned S = nb*block_size lands in
-    each request's granted blocks — `block_ids` is (K, nb) — as ONE
-    donated strided scatter into the (L, n_blocks, block_size, ...)
+def serve_kv_insert_blocks(pool_leaf, batch_leaf, block_ids: jnp.ndarray,
+                           block_size: int):
+    """Paged twin of :func:`serve_kv_insert_slots`: a batch-K prefilled
+    attention leaf (L, K, S, ...) with block-aligned S = nb*block_size
+    lands in each request's granted blocks — `block_ids` is (K, nb) — as
+    ONE donated strided scatter into the (L, n_blocks, block_size, ...)
     pool.  Sentinel ids (>= n_blocks) are dropped."""
     L, K, S = batch_leaf.shape[:3]
     nb = S // block_size
@@ -114,7 +114,7 @@ class SlotCache:
         many requests the prefill batched."""
         idx = jnp.asarray(slots, jnp.int32)
         self.cache["layers"] = jax.tree.map(
-            lambda pool, many: _insert_slots(pool, many, idx),
+            lambda pool, many: serve_kv_insert_slots(pool, many, idx),
             self.cache["layers"], prefill_cache["layers"])
         self.cache["index"] = self.cache["index"].at[idx].set(
             jnp.asarray(prompt_lens, jnp.int32))
@@ -228,7 +228,7 @@ class PagedKVCache:
         """Land a batch-K prefilled cache: attention leaves — prefilled
         at a block-aligned width S = nb*block_size — scatter into each
         request's first nb granted blocks (one donated strided scatter
-        per leaf, :func:`_insert_blocks`); slot-state leaves scatter by
+        per leaf, :func:`serve_kv_insert_blocks`); slot-state leaves scatter by
         slot id exactly like the flat pool."""
         layers = prefill_cache["layers"]
         idx = jnp.asarray(slots, jnp.int32)
@@ -255,11 +255,11 @@ class PagedKVCache:
                         host[b] = tbl[:nb]
                     ids = jnp.asarray(host)
                 new_pool.append(jax.tree.map(
-                    lambda p, m: _insert_blocks(p, m, ids, bs),
+                    lambda p, m: serve_kv_insert_blocks(p, m, ids, bs),
                     self.pool[pos], layers[pos]))
             else:
                 new_pool.append(jax.tree.map(
-                    lambda p, m: _insert_slots(p, m, idx),
+                    lambda p, m: serve_kv_insert_slots(p, m, idx),
                     self.pool[pos], layers[pos]))
         self.pool = tuple(new_pool)
         for slot, n in zip(slots, prompt_lens):
@@ -340,11 +340,11 @@ class PagedKVCache:
         """Land an :meth:`export_blocks` payload in this pool at `slot`
         (which must already hold a block grant at least as long as the
         payload): paged leaves reshape back to one batch-1 block-aligned
-        prefill and reuse the donated :func:`_insert_blocks` scatter into
-        the slot's own granted blocks; slot-state leaves scatter by slot
-        id.  Byte-for-byte: export -> wire -> import preserves every leaf
-        exactly (tests/test_transport.py), which is what makes
-        disaggregated decode bit-identical to single-process."""
+        prefill and reuse the donated :func:`serve_kv_insert_blocks`
+        scatter into the slot's own granted blocks; slot-state leaves
+        scatter by slot id.  Byte-for-byte: export -> wire -> import
+        preserves every leaf exactly (tests/test_transport.py), which is
+        what makes disaggregated decode bit-identical to single-process."""
         bs = self.block_size
         tbl = self.block_tables.get(slot)
         idx = jnp.asarray([slot], jnp.int32)
@@ -366,11 +366,11 @@ class PagedKVCache:
                     lambda l: l.reshape((l.shape[0], 1, nb * bs)
                                         + l.shape[3:]), batch)
                 new_pool[pos] = jax.tree.map(
-                    lambda p, m: _insert_blocks(p, m, ids, bs),
+                    lambda p, m: serve_kv_insert_blocks(p, m, ids, bs),
                     new_pool[pos], batch)
             else:
                 new_pool[pos] = jax.tree.map(
-                    lambda p, m: _insert_slots(p, m, idx),
+                    lambda p, m: serve_kv_insert_slots(p, m, idx),
                     new_pool[pos], batch)
         self.pool = tuple(new_pool)
 

@@ -7,6 +7,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -259,7 +260,7 @@ def test_kv_budgets_tighten_under_energy_pressure():
 def test_wall_probe_record_and_to_ledger():
     probe = WallProbe()
     probe.record("decoder", "decode", 0.25, tokens=4)
-    with probe.span("projector", "stage", tokens=8):
+    with probe.span("tabm.stage.projector", "projector", "stage", tokens=8):
         pass
     assert len(probe) == 2
     ts = [s.t for s in probe.samples()]
@@ -271,6 +272,180 @@ def test_wall_probe_record_and_to_ledger():
     assert led.record("projector", "stage").samples == 1
     probe.clear()
     assert len(probe) == 0
+
+
+def test_sub_spans_leave_ledger_and_calibration_unchanged():
+    def fill(probe, parts):
+        probe.record("projector", "stage", 0.01, tokens=8)
+        for dt in (0.2, 0.3):
+            with probe.span("serve.decode", "decoder", "decode",
+                            tokens=4) as sp:
+                if parts:
+                    sp.part("serve.decode.launch", "decode.launch")
+                    sp.part("serve.decode.wait", "decode.wait")
+                    sp.part("serve.decode.sample", "decode.sample")
+            # the whole span's time, as the test sets it
+            probe._samples[-1] = probe._samples[-1]._replace(dt=dt)
+        if parts:
+            with probe.span("serve.admit", "engine", "admit"):
+                pass
+            probe.record("fn", "compile", 1.5, name="jit.compile")
+
+    plain, split = WallProbe(), WallProbe()
+    fill(plain, parts=False)
+    fill(split, parts=True)
+    assert len(split) > len(plain)
+    parts = [s for s in split.samples() if s.part]
+    assert {s.phase for s in parts} == {"decode.launch", "decode.wait",
+                                        "decode.sample"}
+    assert all(s.brick == "decoder" and s.tokens == 4 for s in parts)
+    assert split.to_ledger().to_dict() == plain.to_ledger().to_dict()
+    a = CostCalibration.from_ledger(plain.to_ledger(), prior=1)
+    b = CostCalibration.from_ledger(split.to_ledger(), prior=1)
+    assert a.sample("decoder") == b.sample("decoder")
+    assert a.sample("projector") == b.sample("projector")
+
+
+def test_span_parts_tile_the_span():
+    probe = WallProbe()
+    with probe.span("serve.decode", "decoder", "decode", tokens=2) as sp:
+        sp.part("serve.decode.launch", "decode.launch")
+        sp.part("serve.decode.wait", "decode.wait")
+        sum(range(10000))
+        sp.part("serve.decode.sample", "decode.sample")
+    *parts, whole = probe.samples()
+    assert [s.name for s in parts] == ["serve.decode.launch",
+                                       "serve.decode.wait",
+                                       "serve.decode.sample"]
+    assert whole.name == "serve.decode" and not whole.part
+    assert sum(s.dt for s in parts) == pytest.approx(whole.dt, abs=1e-9)
+    assert parts[0].t - parts[0].dt == pytest.approx(whole.t - whole.dt,
+                                                     abs=1e-9)
+    assert parts[-1].t == whole.t
+    # a span that ends unmeasured (a failed operation) records nothing
+    with pytest.raises(ValueError):
+        with probe.span("serve.submit", "engine", "submit"):
+            raise ValueError
+    sp = probe.span("serve.prefill", "decoder", "prefill").start()
+    sp.end(keep=False)
+    sp.end()
+    assert len(probe) == 4
+
+
+def test_since_and_dropped_across_an_overflow():
+    probe = WallProbe(maxlen=4)
+    assert probe.seq == 0 and probe.since(0) == []
+    for i in range(3):
+        probe.record("b", "decode", 0.1 * i)
+    cursor = probe.seq
+    assert cursor == 3 and probe.dropped == 0
+    assert [s.seq for s in probe.since(1)] == [1, 2]
+    for i in range(3):
+        probe.record("b", "prefill", 0.1 * i)
+    # six appended into four places: the two oldest pushed out
+    assert probe.dropped == 2 and len(probe) == 4
+    assert [s.seq for s in probe.since(cursor)] == [3, 4, 5]
+    assert [s.seq for s in probe.since(0)] == [2, 3, 4, 5]
+    assert probe.since(0)[0].seq > 0, "a reader behind the bound sees loss"
+    assert probe.since(probe.seq) == []
+    probe.clear()
+    assert probe.dropped == 2 and probe.seq == 6 and probe.since(0) == []
+
+
+def test_program_spans_show_in_a_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+    probe = WallProbe()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with probe.span("serve.decode", "decoder", "decode") as sp:
+            sp.part("serve.decode.launch", "decode.launch")
+            jax.numpy.ones(4).block_until_ready()
+            sp.part("serve.decode.sample", "decode.sample")
+        with probe.span("tabm.commit", "tabm", "commit"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    names = {e.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events}
+    assert {"serve.decode", "serve.decode.launch", "serve.decode.sample",
+            "tabm.commit"} <= names
+    assert len(probe) == 4
+
+
+def test_jit_watch_counts_and_records_spans():
+    from repro.telemetry import probes
+    probe = WallProbe()
+    probes.watch_jit(probe)
+    before = probes.jit_counts()
+
+    def fresh_program(x):
+        return x * 3 + 1
+
+    jax.jit(fresh_program)(jax.numpy.arange(3.0)).block_until_ready()
+    after = probes.jit_counts()
+    assert after["traces"] > before["traces"]
+    assert (after["compiles"] + after["cache_loads"]
+            > before["compiles"] + before["cache_loads"])
+    spans = [s for s in probe.samples() if "fresh_program" in s.brick]
+    assert {s.name for s in spans} == {"jit.trace", "jit.compile"}
+    assert {s.phase for s in spans} <= {"trace", "compile", "cache_load"}
+    assert probe.to_ledger().to_dict() == WallProbe().to_ledger().to_dict()
+
+
+@pytest.mark.parametrize("async_staging", [False, True])
+def test_request_lifecycle_stamps_are_monotonic_and_ordered(async_staging):
+    cfg = get_config("llava-onevision-0.5b").reduced()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, tokens=np.arange(6 + i) + 3, max_new_tokens=3,
+                    vision_feats=rng.standard_normal(
+                        (1, cfg.vision_tokens, cfg.vision_feat_dim)
+                    ).astype(np.float32) * 0.02)
+            for i in range(3)]
+    assert all(r.submit_t is None for r in reqs), "stamped at submit"
+    with ServingEngine(cfg, params, n_slots=2, max_len=128,
+                       async_staging=async_staging) as eng:
+        t0 = time.monotonic()
+        for r in reqs:
+            eng.submit(r)
+        done = eng.run()
+        t1 = time.monotonic()
+        assert len(done) == 3 and all(r.error is None for r in done)
+        for r in done:
+            stamps = [r.submit_t, r.staged_t, r.admit_t, r.first_token_mt,
+                      r.finish_mt]
+            assert None not in stamps
+            assert t0 <= stamps[0] and stamps[-1] <= t1
+            assert stamps == sorted(stamps), stamps
+            # the wall-clock views follow the monotonic stamps
+            assert r.finish_t - r.first_token_t == pytest.approx(
+                r.finish_mt - r.first_token_mt)
+            assert r.e2e_latency == pytest.approx(r.finish_mt - r.submit_t)
+        assert eng.stats.start_t is not None and eng.stats.start_t >= t0
+        assert eng.stats.tokens_per_s() > 0
+        names = {s.name for s in eng.probe.samples()}
+        assert {"serve.submit", "serve.admit", "serve.prefill",
+                "serve.decode", "serve.decode.launch", "serve.decode.wait",
+                "serve.decode.sample", "tabm.acquire", "tabm.commit",
+                "tabm.wait_ready"} <= names
+        assert any(n.startswith("tabm.stage.") for n in names)
+        # the decode parts tile each decode span
+        samples = eng.probe.samples()
+        whole = [s for s in samples if s.phase == "decode"]
+        for phase in ("decode.launch", "decode.wait", "decode.sample"):
+            assert len([s for s in samples if s.phase == phase]) == \
+                len(whole)
+        for w in whole:
+            parts = [s for s in samples if s.part and s.t <= w.t
+                     and s.t - s.dt >= w.t - w.dt - 1e-9]
+            assert sum(s.dt for s in parts) == pytest.approx(w.dt,
+                                                             abs=1e-9)
+        # the per-step trace event is gone; the cohort event stays
+        events = {e for e, _r, _t in eng.trace}
+        assert "decode_cohort" in events and "decode_step" not in events
+        assert set(eng.jit_counts()) == {"traces", "compiles",
+                                         "cache_loads"}
 
 
 def test_engine_probes_and_monotonic_trace():
