@@ -14,7 +14,7 @@ Two hot-context kinds are scanned:
   decode step loop and the plan's run/staging paths).  These run host
   Python between device dispatches, so a stray sync serializes the
   pipeline; the same calls are flagged.  Deliberate syncs (the plan's
-  residency trace points, the engine's per-token sampling reads) carry
+  residency trace points, the engine's per-step sampling read) carry
   ``# replint: disable=host-sync`` pragmas with their one-line why.
 
 ``float()``/``int()`` over shape/ndim/size/len expressions or literals

@@ -123,7 +123,7 @@ from repro.core.scheduler import class_staging_budgets, kv_block_budgets
 from repro.core.tabm import SlotClassPool, TABMError
 from repro.models import model as M
 from repro.serving.kv_cache import PagedKVCache, SlotCache, bucket_length
-from repro.serving.sampling import sample
+from repro.serving.sampling import greedy, sample_rows
 from repro.telemetry.calibration import CostCalibration
 from repro.telemetry.ledger import Ledger
 from repro.telemetry.probes import WallProbe, jit_counts, watch_jit
@@ -227,6 +227,9 @@ class EngineStats:
     steps: int = 0
     finished: int = 0
     failed: int = 0
+    # device-to-host reads made to sample decode tokens: one per cohort
+    # step, so decoded_tokens / sample_reads is the mean cohort rows
+    sample_reads: int = 0
     start_t: Optional[float] = None      # first decode step (monotonic)
 
     def tokens_per_s(self) -> float:
@@ -535,6 +538,10 @@ class ServingEngine:
         self._prefill_cache: Dict[int, Any] = {}
         # one compiled cohort decode step per cohort-size bucket
         self._cohort_cache: Dict[int, Any] = {}
+        # compiled greedy picks by logits (shape, dtype, sharding): the
+        # cohort buckets' are built with their step (_cohort_fn), so a
+        # serving window never compiles one
+        self._picks: Dict[tuple, Any] = {}
         # staged-slab dedup registry: share key -> owning request
         self.share_staged = bool(share_staged and self.tabm is not None)
         self._stage_keys: Dict[tuple, Request] = {}
@@ -708,7 +715,8 @@ class ServingEngine:
         stated tolerance of this composed body.  Both flags (fused?,
         interpret?) resolve HERE, at build time, outside the jit — the
         dispatch rule of kernels/dispatch — and are recorded in
-        ``cohort_path``."""
+        ``cohort_path``.  Building a bucket also compiles the greedy pick
+        over its logits (:meth:`_jit_cohort`)."""
         if bc not in self._cohort_cache:
             cfg = self.cfg
             paged = self.slots.paged
@@ -726,10 +734,8 @@ class ServingEngine:
                 # decoder placed on a submesh of several devices runs its
                 # step on all of them.  Off-TPU, interpret mode would be
                 # far slower than the composed XLA path
-                devices = {d for leaf in jax.tree.leaves(self.slots.pool)
-                           for d in leaf.sharding.device_set}
                 use_fused = (fused_supported(cfg) and not resolve_interpret()
-                             and len(devices) == 1)
+                             and len(self._pool_devices()) == 1)
             interp = bool(use_fused) and resolve_interpret(None)
             self.cohort_path = ("fused" if use_fused else "composed", interp)
             name = f"serve_cohort_b{bc}"       # jit_<name> in the trace
@@ -743,8 +749,7 @@ class ServingEngine:
                         interpret=interp)
 
                 fn.__name__ = fn.__qualname__ = name
-                self._cohort_cache[bc] = jax.jit(fn, donate_argnums=(5,))
-                return self._cohort_cache[bc]
+                return self._jit_cohort(bc, fn)
 
             @jax.named_scope("serve_decode")
             def fn(p, tokens, lengths, slot_ids, tables, pool):
@@ -788,8 +793,39 @@ class ServingEngine:
                 return logits, tuple(out)
 
             fn.__name__ = fn.__qualname__ = name
-            self._cohort_cache[bc] = jax.jit(fn, donate_argnums=(5,))
+            return self._jit_cohort(bc, fn)
         return self._cohort_cache[bc]
+
+    def _pool_devices(self) -> set:
+        return {d for leaf in jax.tree.leaves(self.slots.pool)
+                for d in leaf.sharding.device_set}
+
+    def _jit_cohort(self, bc: int, fn):
+        """Jit cohort step ``fn`` as bucket ``bc``'s, and compile the
+        greedy pick over its (bc, V) logits now, so that the first step
+        at this bucket compiles nothing more.  The logits' shape comes
+        from an abstract trace that the step's first call reuses.  On a
+        pool spread over several devices the logits' placement is known
+        only from a real step, so the pick compiles at the first one."""
+        step = self._cohort_cache[bc] = jax.jit(fn, donate_argnums=(5,))
+        args = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in
+                ((bc, 1), (bc,), (bc,), (bc, self.slots.blocks_per_slot))]
+        logits, _ = step.eval_shape(self.params, *args, self.slots.pool)
+        devices = self._pool_devices()
+        if len(devices) == 1:
+            self._greedy_pick(logits.shape, logits.dtype,
+                              jax.sharding.SingleDeviceSharding(
+                                  devices.pop()))
+        return step
+
+    def _greedy_pick(self, shape, dtype, sharding):
+        """The compiled argmax for logits of this shape, dtype and
+        placement, compiled on first use."""
+        key = (tuple(shape), np.dtype(dtype), sharding)
+        if key not in self._picks:
+            self._picks[key] = jax.jit(greedy).lower(jax.ShapeDtypeStruct(
+                shape, dtype, sharding=sharding)).compile()
+        return self._picks[key]
 
     def _stage(self, depth_scale: float = 1.0):
         """Synchronous fallback producer (``async_staging=False``): run the
@@ -1153,7 +1189,7 @@ class ServingEngine:
             self.stats.prefills += 1
             self._trace_event("prefill", req.rid)
             # first token from this request's row of the prefill logits
-            tok = self._pick(logits[b:b + 1], req)
+            tok = self._pick(logits[b:b + 1], [req])
             req.out_tokens.append(int(tok[0]))
             req.first_token_mt = time.monotonic()
         if len(group) > 1:                     # the acceptance evidence
@@ -1303,11 +1339,20 @@ class ServingEngine:
                 with self.probe.span("serve.park", "engine", "park"):
                     time.sleep(0.005)
 
-    def _pick(self, logits, req: Request):
-        if req.temperature == 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def _pick(self, logits, reqs: List[Request]):
+        """The next token of every row of ``logits`` (n, V), in one device
+        call: an (n,) int32 device array.  Row b is ``reqs[b]``'s; rows
+        past ``len(reqs)`` are cohort padding, picked greedily and
+        ignored.  When every request is greedy the rows take the
+        compiled argmax (ties to the lowest index); otherwise all go
+        through ``sample_rows`` with one key split for the call."""
+        if all(r.temperature == 0.0 for r in reqs):
+            return self._greedy_pick(logits.shape, logits.dtype,
+                                     logits.sharding)(logits)
+        temps = np.zeros((logits.shape[0],), np.float32)
+        temps[:len(reqs)] = [r.temperature for r in reqs]
         self.key, k = jax.random.split(self.key)
-        return sample(logits, k, temperature=req.temperature)
+        return sample_rows(logits, k, jnp.asarray(temps))
 
     def _buckets(self):
         caps = [b for b in (128, 256, 512, 1024, 2048, 4096)
@@ -1339,10 +1384,10 @@ class ServingEngine:
             slot_ids[b] = slot
         # measured decode span for the telemetry ledger, in three parts
         # that tile it: launch (dispatch of the cohort step), wait (the
-        # first row's sampling read, which returns once the device step
-        # is done) and sample (the other rows).  The per-token reads
-        # sync, so the span is true wall time of one cohort step (host
-        # clocks only — replint-clean)
+        # pick's dispatch and its one read, which returns once the device
+        # step is done) and sample (the host bookkeeping of every row).
+        # The read syncs, so the span is true wall time of one cohort
+        # step (host clocks only — replint-clean)
         span = self.probe.span("serve.decode", "decoder", "decode",
                                tokens=len(cohort)).start()
         span.part("serve.decode.launch", "decode.launch")
@@ -1354,14 +1399,20 @@ class ServingEngine:
         self.stats.steps += 1
         self._trace_event("decode_cohort", len(cohort))
         span.part("serve.decode.wait", "decode.wait")
+        # every row, padding included, in one pick: one compiled pick per
+        # bucket, where slicing off the live rows would compile one per
+        # live count
+        picked = self._pick(logits, [self.live[s] for s in cohort])
+        # deliberate per-step sampling read: the ids feed the next step's
+        # host-side token buffer and EOS checks
+        toks = np.asarray(picked).tolist()  # replint: disable=host-sync
+        self.stats.sample_reads += 1
+        span.part("serve.decode.sample", "decode.sample")
 
         finished = []
         for b, slot in enumerate(cohort):
             req = self.live[slot]
-            tok = self._pick(logits[b:b + 1], req)
-            # deliberate per-token sampling read: the sampled id feeds the
-            # next step's host-side token buffer and EOS check
-            t = int(tok[0])  # replint: disable=host-sync
+            t = toks[b]
             req.out_tokens.append(t)
             self.slots.bump(slot)
             self.stats.decoded_tokens += 1
@@ -1370,8 +1421,6 @@ class ServingEngine:
                     or over_len):
                 req.finish_mt = time.monotonic()
                 finished.append(slot)
-            if b == 0:
-                span.part("serve.decode.sample", "decode.sample")
         span.end()
         for slot in finished:
             req = self.live.pop(slot)

@@ -12,6 +12,18 @@ def greedy(logits: jnp.ndarray) -> jnp.ndarray:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@jax.jit
+def sample_rows(logits: jnp.ndarray, key, temps: jnp.ndarray) -> jnp.ndarray:
+    """logits (B, V), per-row temperatures (B,) f32 -> tokens (B,) int32.
+
+    A row at temperature 0 takes the argmax; the others draw from
+    ``logits / max(t, 1e-4)``, all from the one ``key``."""
+    logits = logits.astype(jnp.float32)
+    t = jnp.maximum(temps, 1e-4)[:, None]
+    drawn = jax.random.categorical(key, logits / t, axis=-1)
+    return jnp.where(temps > 0, drawn, greedy(logits)).astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("top_k", "top_p"))
 def sample(logits: jnp.ndarray, key, *, temperature: float = 1.0,
            top_k: int = 0, top_p: float = 1.0) -> jnp.ndarray:

@@ -29,8 +29,8 @@ loads, and records each as a ``jit.trace`` / ``jit.compile`` span (brick
 Measurement caveat, stated once: on asynchronous backends a span that
 does not end at an existing host sync measures *dispatch*, not device
 completion.  The engine's prefill and decode spans end at syncs it
-already pays (the per-token sampling read after decode, the first-token
-reads after prefill), so those are true wall times; the plan's per-brick
+already pays (the one sampling read after each decode step, the
+first-token reads after prefill), so those are true wall times; the plan's per-brick
 staging spans are dispatch-inclusive lower bounds, still ordered
 correctly for *relative* calibration.
 """
