@@ -11,9 +11,9 @@ window"):
   activation, and down GEMM, on a grid over ``d_ff`` tiles that
   accumulates the down projection in f32;
 * :func:`kv_row_scatter_pallas` — the paged single-position K/V scatter:
-  grid (bc,), scalar-prefetched (block, offset) per cohort row, the pool
-  aliased in place (donation) and ONLY the one new row's block written —
-  sentinel rows (``blk == n_blocks``) write nothing at all.
+  grid (layer tiles, bc), scalar-prefetched (block, offset) per cohort
+  row, the pool aliased in place (donation) and ONLY each live row's own
+  block rewritten — sentinel rows (``blk == n_blocks``) write nothing.
 
 Layout rules the TPU compiler (Mosaic) imposes, and how each is met:
 
@@ -26,6 +26,16 @@ Layout rules the TPU compiler (Mosaic) imposes, and how each is met:
 * a column tile of a packed matrix is not lane-aligned (``tile / per_word``
   int32 words), so those operands are pre-tiled outside the kernel to
   ``(n_tiles, rows, cols / n_tiles)`` and indexed on the leading axis.
+* the scatter works on the lane-dense pool ``(L, n_blocks, bs, KV*hd)``,
+  the layout the pool rests in and the gather reads, so XLA copies no pool
+  into a layout of the kernel's own (with ``(KV, hd)`` minor, llava's 2 x
+  64, Mosaic's layout was 2x lane-padded and every step copied the pool
+  there and back).  Its pool block is a row's whole ``(bs, KV*hd)`` block
+  for a tile of layers: a one-row ``(1, KV*hd)`` block is refused (the
+  second-minor block dim must be a multiple of 8 or the whole dim), and so
+  is storing at a dynamic sublane offset into a larger block (Mosaic
+  cannot prove the index a multiple of 8), so the row goes in by an iota
+  mask over the block, a read-modify-write.
 
 Numerics: the unpack replicates ``core.quantize.dequantize``'s cast chain
 (int unpack -> f32 -> x scales -> cast) bit for bit, and each GEMM rounds
@@ -313,43 +323,81 @@ def fused_mlp_pallas(h, w_up, w_down, w_gate=None, *,
     return out.reshape(h.shape)
 
 
+def _visits(blk, n_blocks: int):
+    """The pool block each cohort row's grid step visits: a live row its
+    own; a sentinel row (``blk == n_blocks``) its nearest live
+    neighbour's — the one before it, else the one after (block 0 if no
+    row is live).  Live rows own distinct blocks, so every block's visits
+    are consecutive steps, and the pipeline neither refetches nor writes
+    back a block between them: a sentinel row moves no pool bytes."""
+    bc = blk.shape[0]
+    idx = jnp.arange(bc, dtype=jnp.int32)
+    live = blk < n_blocks
+    before = jax.lax.cummax(jnp.where(live, idx, -1))
+    after = jax.lax.cummin(jnp.where(live, idx, bc), reverse=True)
+    src = jnp.where(before >= 0, before, after)
+    return jnp.where(src < bc, blk[jnp.minimum(src, bc - 1)], 0)
+
+
+def _layer_tile(L: int, block_bytes: int) -> int:
+    """Layers per scatter block: the largest divisor of ``L`` whose K and
+    V blocks, in and out and double-buffered, fit ``_TILE_BUDGET``."""
+    return max((t for t in range(1, L + 1)
+                if L % t == 0 and 8 * t * block_bytes <= _TILE_BUDGET),
+               default=1)
+
+
 def kv_row_scatter_pallas(blk, off, k_rows, v_rows, k_pool, v_pool, *,
                           interpret: bool = False):
     """Scatter each group's new K/V position per cohort row into the pool.
 
-    k_pool/v_pool (L, n_blocks, bs, KV, hd) donated (aliased in place);
-    k_rows/v_rows (L, bc, KV, hd); blk/off (bc,) int32 scalar-prefetched.
-    One program per (group, row); HBM traffic is the written rows
-    themselves.  Sentinel rows (blk == n_blocks, the padded-cohort marker)
-    skip the store entirely — the aliased block keeps its pool content,
-    the drop semantics of the composed ``.at[...].set(mode="drop")``
-    without touching the pool."""
-    L, n_blocks, bs, KV, hd = k_pool.shape
+    k_pool/v_pool lane-dense (L, n_blocks, bs, KV*hd), donated (aliased in
+    place); k_rows/v_rows (L, bc, ...) with KV*hd elements per row;
+    blk/off (bc,) int32 scalar-prefetched.  Grid (layer tiles, bc): each
+    step reads the row's whole (bs, KV*hd) block of a tile of layers and
+    writes it back with the one new row selected in by an iota mask.
+    Mosaic refuses the one-row block (its second-minor dim must be a
+    multiple of 8 or the whole dim) and a dynamic sublane index into a
+    block, so the row is written by a read-modify-write of its block, 16
+    KB per layer and row at llava's widths.  A block is copied in on its
+    first visit only (:func:`_visits`); sentinel rows write nothing —
+    the block they revisit leaves the kernel as it came in or as its live
+    row wrote it, the drop semantics of the composed
+    ``.at[...].set(mode="drop")``."""
+    L, n_blocks, bs, F = k_pool.shape
+    bc = k_rows.shape[1]
+    lt = _layer_tile(L, bs * F * k_pool.dtype.itemsize)
+    k_rows = k_rows.reshape(L, bc, 1, F)
+    v_rows = v_rows.reshape(L, bc, 1, F)
 
-    row_spec = pl.BlockSpec((1, 1, KV, hd),
-                            lambda g, b, blk, off: (g, b, 0, 0))
-    # clamp the index map for sentinel rows — the selected block is never
-    # written for them, it only has to be a legal address
-    pool_spec = pl.BlockSpec(
-        (1, 1, 1, KV, hd),
-        lambda g, b, blk, off: (g, jnp.minimum(blk[b], n_blocks - 1),
-                                off[b], 0, 0))
+    row_spec = pl.BlockSpec((lt, 1, 1, F),
+                            lambda g, b, blk, vis, off: (g, b, 0, 0))
+    pool_spec = pl.BlockSpec((lt, 1, bs, F),
+                             lambda g, b, blk, vis, off: (g, vis[b], 0, 0))
 
-    def body(blk_ref, off_ref, krow_ref, vrow_ref, kin_ref, vin_ref,
-             kout_ref, vout_ref):
-        del off_ref, kin_ref, vin_ref
+    def body(blk_ref, vis_ref, off_ref, krow_ref, vrow_ref, kin_ref,
+             vin_ref, kout_ref, vout_ref):
         b = pl.program_id(1)
+        first = (b == 0) | (vis_ref[b] != vis_ref[jnp.maximum(b - 1, 0)])
+
+        @pl.when(first)
+        def _():
+            kout_ref[...] = kin_ref[...]
+            vout_ref[...] = vin_ref[...]
 
         @pl.when(blk_ref[b] < n_blocks)
         def _():
-            kout_ref[...] = krow_ref[...][:, :, None].astype(
-                kout_ref.dtype)
-            vout_ref[...] = vrow_ref[...][:, :, None].astype(
-                vout_ref.dtype)
+            at = jax.lax.broadcasted_iota(jnp.int32, kout_ref.shape, 2) \
+                == off_ref[b]
+            for row_ref, out_ref in ((krow_ref, kout_ref),
+                                     (vrow_ref, vout_ref)):
+                row = jnp.broadcast_to(row_ref[...].astype(out_ref.dtype),
+                                       out_ref.shape)
+                out_ref[...] = jnp.where(at, row, out_ref[...])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(L, k_rows.shape[1]),
+        num_scalar_prefetch=3,
+        grid=(L // lt, bc),
         in_specs=[row_spec, row_spec, pool_spec, pool_spec],
         out_specs=[pool_spec, pool_spec],
     )
@@ -358,7 +406,10 @@ def kv_row_scatter_pallas(blk, off, k_rows, v_rows, k_pool, v_pool, *,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={5: 0, 6: 1},
+        # in order: a block's visits must be consecutive steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="kv_row_scatter",
-    )(blk, off, k_rows, v_rows, k_pool, v_pool)
+    )(blk, _visits(blk, n_blocks), off, k_rows, v_rows, k_pool, v_pool)

@@ -32,8 +32,9 @@ import jax.numpy as jnp
 from repro.core.quantize import QTensor, dequantize
 from repro.kernels.dispatch import resolve_interpret
 from repro.kernels.fused_decode import kernel as K
-from repro.kernels.fused_decode.ref import (ref_cohort_step, ref_fused_mlp,
-                                            ref_fused_qkv, ref_kv_scatter)
+from repro.kernels.fused_decode.ref import (paged_context, ref_cohort_step,
+                                            ref_fused_mlp, ref_fused_qkv,
+                                            ref_kv_scatter)
 
 
 def fused_qkv(h, wq, wk, wv, bq=None, bk=None, bv=None, *,
@@ -64,7 +65,8 @@ def kv_scatter(blk, off, k_rows, v_rows, k_pool, v_pool, *,
                use_kernel: Optional[bool] = None,
                interpret: Optional[bool] = None):
     """Write each cohort row's new K/V position (all layer groups at once)
-    into the paged pool.
+    into the lane-dense paged pool: rows (L, bc, KV, hd), pools (L,
+    n_blocks, bs, KV*hd).
 
     Pools are donated (aliased); sentinel rows write nothing.
     ``interpret`` resolves through kernels/dispatch."""
@@ -109,8 +111,8 @@ def _fused_cohort_step(params, cfg, tokens, lengths, slot_ids, tables,
     projection, the LM head — is the same shared code the composed path
     runs, so equality with the oracle reduces to the per-kernel
     contracts.  The new K/V rows come out of the scan stacked and hit the
-    pool in ONE aliased scatter kernel (grid (L, bc)) instead of the
-    composed path's whole-pool gather-update-rescatter."""
+    pool in ONE aliased scatter kernel (grid (layer tiles, bc)) instead
+    of the composed path's whole-pool gather-update-rescatter."""
     from repro.distributed.sharding import constrain_residual
     from repro.models import attention as attn
     from repro.models import model as M
@@ -118,11 +120,8 @@ def _fused_cohort_step(params, cfg, tokens, lengths, slot_ids, tables,
 
     del slot_ids                       # every position is paged (supported
     #                                    archs have no slot-state layers)
-    bc = tokens.shape[0]
     bs = block_size
-    W = tables.shape[1]
     k_pool, v_pool = pool[0]
-    L = k_pool.shape[0]
 
     index = jnp.asarray(lengths)
     positions = index[:, None].astype(jnp.int32)
@@ -131,11 +130,11 @@ def _fused_cohort_step(params, cfg, tokens, lengths, slot_ids, tables,
 
     x = M._embed(params, cfg, tokens)
     # cohort context gather — identical to the composed path (the fused
-    # kernels replace the *scatter* side; reads stay one gather)
-    gk = jnp.take(k_pool, tables, axis=1, mode="fill", fill_value=0).reshape(
-        (L, bc, W * bs) + k_pool.shape[3:])
-    gv = jnp.take(v_pool, tables, axis=1, mode="fill", fill_value=0).reshape(
-        (L, bc, W * bs) + v_pool.shape[3:])
+    # kernels replace the *scatter* side; reads stay one gather on the
+    # lane-dense pool, and only the gathered context takes (KV, hd))
+    heads = (cfg.n_kv_heads, cfg.hd)
+    gk = paged_context(k_pool, tables, heads)
+    gv = paged_context(v_pool, tables, heads)
     blk = jnp.take_along_axis(tables, (lengths // bs)[:, None], axis=1)[:, 0]
     off = lengths % bs
 

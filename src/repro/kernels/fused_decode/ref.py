@@ -48,35 +48,43 @@ def ref_fused_mlp(h, w_up, w_down, w_gate=None, *, act: str):
     return mlp_mod.apply_mlp(p, cfg, h)
 
 
+def paged_context(leaf, tables, heads):
+    """Each row's context from a lane-dense paged leaf (L, n_blocks, bs,
+    KV*hd) through its block table (bc, W): (L, bc, W*bs) + ``heads``,
+    the composed (KV, hd) view.  Sentinel ids gather zeros."""
+    L, _, bs, _ = leaf.shape
+    bc, W = tables.shape
+    return jnp.take(leaf, tables, axis=1, mode="fill",
+                    fill_value=0).reshape((L, bc, W * bs) + tuple(heads))
+
+
 def ref_kv_scatter(blk, off, k_rows, v_rows, k_pool, v_pool):
     """The engine's paged single-position scatter, all layer groups at
-    once: sentinel block ids (== n_blocks) fall out of range and drop."""
+    once: rows (L, bc, KV, hd) land as (L, bc, KV*hd) lanes; sentinel
+    block ids (== n_blocks) fall out of range and drop."""
+    L, bc = k_rows.shape[:2]
     k_pool = k_pool.at[:, blk, off].set(
-        k_rows.astype(k_pool.dtype), mode="drop")
+        k_rows.reshape(L, bc, -1).astype(k_pool.dtype), mode="drop")
     v_pool = v_pool.at[:, blk, off].set(
-        v_rows.astype(v_pool.dtype), mode="drop")
+        v_rows.reshape(L, bc, -1).astype(v_pool.dtype), mode="drop")
     return k_pool, v_pool
 
 
 def ref_cohort_step(params, cfg, tokens, lengths, slot_ids, tables, pool, *,
                     block_size: int, paged):
-    """Today's three dispatches, verbatim (the body ServingEngine compiled
-    before the fused path existed): gather each row's context from the
-    paged pool, run ONE ``lm_decode_step`` over the cohort, scatter the
-    new K/V position back through the block tables.  This is the oracle
-    the fused step must match bit for bit."""
+    """The composed cohort step, three dispatches (the body
+    ServingEngine compiles when the step is not fused): gather each row's
+    context from the paged pool, run ONE ``lm_decode_step`` over the
+    cohort, scatter the new K/V position back through the block tables.
+    This is the oracle the fused step must match."""
     bc = tokens.shape[0]
     bs = block_size
-    W = tables.shape[1]
+    heads = (cfg.n_kv_heads, cfg.hd)
     layers = []
     for pos, is_paged in enumerate(paged):
         if is_paged:
             layers.append(jax.tree.map(
-                lambda l: jnp.take(
-                    l, tables, axis=1, mode="fill",
-                    fill_value=0).reshape(
-                        (l.shape[0], bc, W * bs) + l.shape[3:]),
-                pool[pos]))
+                lambda l: paged_context(l, tables, heads), pool[pos]))
         else:
             layers.append(jax.tree.map(
                 lambda l: jnp.take(l, slot_ids, axis=1,
@@ -94,7 +102,8 @@ def ref_cohort_step(params, cfg, tokens, lengths, slot_ids, tables, pool, *,
                 idx = lengths.reshape((1, bc) + (1,) * (nl.ndim - 2))
                 row = jnp.take_along_axis(nl, idx, axis=2)
                 return l.at[:, blk, off].set(
-                    row[:, :, 0].astype(l.dtype), mode="drop")
+                    row.reshape(l.shape[0], bc, -1).astype(l.dtype),
+                    mode="drop")
             out.append(jax.tree.map(scat, pool[pos], new["layers"][pos]))
         else:
             out.append(jax.tree.map(

@@ -698,30 +698,30 @@ class ServingEngine:
         return slots
 
     def _cohort_fn(self, bc: int):
-        """The compiled cohort decode step for cohort-size bucket `bc`:
-        gather each row's context from the paged pool through its block
-        table — (bc, W) ids -> (G, bc, W*block_size, ...) — run ONE
+        """The compiled cohort decode step for cohort-size bucket `bc`
+        (kernels/fused_decode.cohort_step).  The composed body gathers
+        each row's context from the paged pool through its block table —
+        (bc, W) ids -> (G, bc, W*block_size, KV, hd) — runs ONE
         ``lm_decode_step`` over the cohort (per-row lengths as the
         index vector; rows are independent, so each decodes exactly as
-        it would alone), then scatter the one new K/V position back
+        it would alone), then scatters the one new K/V position back
         into each row's current block and the updated slot state back
         by slot id.  Padded rows carry sentinel ids: gathers fill
         zeros (masked by length 0), scatters drop — padding costs no
         host branching and writes nothing.
 
         ``use_fused`` (engine flag) swaps the body for the fused
-        Pallas step (kernels/fused_decode.cohort_step): in-VMEM weight
-        unpack + QKV/MLP GEMMs + single-position KV scatter, within a
-        stated tolerance of this composed body.  Both flags (fused?,
-        interpret?) resolve HERE, at build time, outside the jit — the
-        dispatch rule of kernels/dispatch — and are recorded in
+        Pallas step: in-VMEM weight unpack + QKV/MLP GEMMs +
+        single-position KV scatter, within a stated tolerance of the
+        composed body.  Both flags (fused?, interpret?) resolve HERE, at
+        build time, outside the jit — the dispatch rule of
+        kernels/dispatch — and are recorded in
         ``cohort_path``.  Building a bucket also compiles the greedy pick
         over its logits (:meth:`_jit_cohort`)."""
         if bc not in self._cohort_cache:
             cfg = self.cfg
             paged = self.slots.paged
             bs = self.slots.block_size
-            W = self.slots.blocks_per_slot
 
             from repro.kernels.dispatch import resolve_interpret
             from repro.kernels.fused_decode import (cohort_step,
@@ -739,58 +739,13 @@ class ServingEngine:
             interp = bool(use_fused) and resolve_interpret(None)
             self.cohort_path = ("fused" if use_fused else "composed", interp)
             name = f"serve_cohort_b{bc}"       # jit_<name> in the trace
-            if use_fused:
-
-                @jax.named_scope("serve_decode")
-                def fn(p, tokens, lengths, slot_ids, tables, pool):
-                    return cohort_step(
-                        p, cfg, tokens, lengths, slot_ids, tables, pool,
-                        block_size=bs, paged=paged, use_fused=True,
-                        interpret=interp)
-
-                fn.__name__ = fn.__qualname__ = name
-                return self._jit_cohort(bc, fn)
 
             @jax.named_scope("serve_decode")
             def fn(p, tokens, lengths, slot_ids, tables, pool):
-                layers = []
-                for pos, is_paged in enumerate(paged):
-                    if is_paged:
-                        layers.append(jax.tree.map(
-                            lambda l: jnp.take(
-                                l, tables, axis=1, mode="fill",
-                                fill_value=0).reshape(
-                                    (l.shape[0], bc, W * bs)
-                                    + l.shape[3:]),
-                            pool[pos]))
-                    else:
-                        layers.append(jax.tree.map(
-                            lambda l: jnp.take(l, slot_ids, axis=1,
-                                               mode="fill", fill_value=0),
-                            pool[pos]))
-                cache = {"layers": tuple(layers), "index": lengths}
-                logits, new = M.lm_decode_step(p, cfg, tokens, cache)
-                # the block holding each row's newly written position
-                blk = jnp.take_along_axis(
-                    tables, (lengths // bs)[:, None], axis=1)[:, 0]
-                off = lengths % bs
-                out = []
-                for pos, is_paged in enumerate(paged):
-                    if is_paged:
-                        def scat(l, nl):
-                            idx = lengths.reshape(
-                                (1, bc) + (1,) * (nl.ndim - 2))
-                            row = jnp.take_along_axis(nl, idx, axis=2)
-                            return l.at[:, blk, off].set(
-                                row[:, :, 0].astype(l.dtype), mode="drop")
-                        out.append(jax.tree.map(
-                            scat, pool[pos], new["layers"][pos]))
-                    else:
-                        out.append(jax.tree.map(
-                            lambda l, nl: l.at[:, slot_ids].set(
-                                nl.astype(l.dtype), mode="drop"),
-                            pool[pos], new["layers"][pos]))
-                return logits, tuple(out)
+                return cohort_step(
+                    p, cfg, tokens, lengths, slot_ids, tables, pool,
+                    block_size=bs, paged=paged, use_fused=use_fused,
+                    interpret=interp)
 
             fn.__name__ = fn.__qualname__ = name
             return self._jit_cohort(bc, fn)

@@ -9,21 +9,33 @@ recompile):
   grow to ``max_len`` anyway; kept as the reference layout.
 
 * :class:`PagedKVCache` — the paged pool the engine's decode cohort
-  runs on.  Attention K/V live as fixed-size **blocks** ``(n_blocks,
-  block_size, ...)`` instead of per-slot rows; every admitted request
-  owns a **block table** (host-side list of granted block ids) and
-  decode gathers its context as ``pool[table]``.  SSM / linear-attention
-  state has no length axis, so those group positions stay slot-indexed.
-  Admission *grants* a request exactly the blocks its lifetime needs
-  (block-aligned prefill bucket + decode growth), charged per slot class
+  runs on.  Attention K/V live as fixed-size **blocks** instead of
+  per-slot rows; every admitted request owns a **block table**
+  (host-side list of granted block ids) and decode gathers its context
+  as ``pool[table]``.  SSM / linear-attention state has no length axis,
+  so those group positions stay slot-indexed.  Admission *grants* a
+  request exactly the blocks its lifetime needs (block-aligned prefill
+  bucket + decode growth), charged per slot class
   (``core/scheduler.kv_block_budgets``), and retirement returns them to
   the free deque immediately — the continuous-batching property that a
   finishing request's memory is grantable at the very next step.
 
+A paged leaf is **lane-dense**: ``(L, n_blocks, block_size, KV*hd)``,
+the KV heads and head dim fused into one trailing axis.  The TPU tiles
+an array's two minor dims by (8, 128); with ``(KV, hd)`` minor (llava's
+2 x 64) neither fills 128 lanes, so XLA rests the pool with its *block*
+axis on the lanes and every gather, Mosaic scatter and prefill insert
+first copies the whole pool into a layout of its own.  With one fused
+axis the resting layout, the gather's and the scatter kernel's coincide
+and no program relayouts the pool.  The composed ``(KV, hd)`` view exists
+only on arrays a row owns: a prefill batch before it lands, the new K/V
+rows, the gathered context (``kernels/fused_decode.paged_context``).
+
 Both pools land grouped batch-B prefills in ONE donated strided scatter
 per leaf (``insert_many``): the flat pool scatters rows, the paged pool
-reshapes the block-aligned prefill width ``(B, nb*block_size)`` into
-``(B*nb, block_size)`` and scatters into the owners' granted blocks.
+reshapes the block-aligned prefill ``(L, B, nb*block_size, KV, hd)``
+into ``(L, B*nb, block_size, KV*hd)`` and scatters into the owners'
+granted blocks.
 
 Out-of-range sentinels make cohort padding free: a padded cohort row
 carries slot id ``n_slots`` and block id ``n_blocks`` — device gathers
@@ -61,12 +73,12 @@ def serve_kv_insert_blocks(pool_leaf, batch_leaf, block_ids: jnp.ndarray,
     """Paged twin of :func:`serve_kv_insert_slots`: a batch-K prefilled
     attention leaf (L, K, S, ...) with block-aligned S = nb*block_size
     lands in each request's granted blocks — `block_ids` is (K, nb) — as
-    ONE donated strided scatter into the (L, n_blocks, block_size, ...)
-    pool.  Sentinel ids (>= n_blocks) are dropped."""
+    ONE donated strided scatter into the lane-dense (L, n_blocks,
+    block_size, KV*hd) pool.  Only the batch is reshaped, never the pool.
+    Sentinel ids (>= n_blocks) are dropped."""
     L, K, S = batch_leaf.shape[:3]
     nb = S // block_size
-    resh = batch_leaf.reshape((L, K * nb, block_size)
-                              + batch_leaf.shape[3:])
+    resh = batch_leaf.reshape(L, K * nb, block_size, pool_leaf.shape[-1])
     return pool_leaf.at[:, block_ids.reshape(-1)].set(
         resh.astype(pool_leaf.dtype), mode="drop")
 
@@ -147,7 +159,8 @@ class PagedKVCache:
 
     Device layout, one entry per group position (``paged_positions``):
 
-    * paged (attention K/V): leaves ``(L, n_blocks, block_size, ...)``;
+    * paged (attention K/V): lane-dense leaves ``(L, n_blocks,
+      block_size, KV*hd)`` (module docstring);
     * slot state (mamba / linear attention): leaves ``(L, n_slots, ...)``
       exactly as :func:`repro.models.decoder.init_cache` builds them.
 
@@ -177,11 +190,13 @@ class PagedKVCache:
         pool = []
         for pos, paged in enumerate(self.paged):
             if paged:
-                # reuse the (L, n_slots, block_size, ...) template leaf
-                # for dtype/trailing dims; blocks replace the slot axis
+                # reuse the (L, n_slots, block_size, KV, hd) template
+                # leaf for its dtype; blocks replace the slot axis and
+                # the heads fuse into one lane axis
                 pool.append(jax.tree.map(
                     lambda l: jnp.zeros(
-                        (l.shape[0], self.n_blocks) + l.shape[2:], l.dtype),
+                        (l.shape[0], self.n_blocks, block_size,
+                         int(np.prod(l.shape[3:]))), l.dtype),
                     base[pos]))
             else:
                 pool.append(base[pos])
@@ -311,7 +326,7 @@ class PagedKVCache:
     def export_blocks(self, slot: int, n_blocks: int) -> List[List[Any]]:
         """Pull one request's prefill-written cache off the device for
         the wire: per group position, the flat leaf list — paged
-        positions as ``(L, nb, block_size, ...)`` host arrays holding the
+        positions as ``(L, nb, block_size, KV*hd)`` host arrays holding the
         first ``n_blocks`` granted blocks (the *written* ones — never the
         whole lane), slot-state positions as the request's ``(L, 1,
         ...)`` row.  Tree structure is not exported; the importing pool
@@ -339,8 +354,8 @@ class PagedKVCache:
     def import_blocks(self, slot: int, payload: List[List[Any]]) -> None:
         """Land an :meth:`export_blocks` payload in this pool at `slot`
         (which must already hold a block grant at least as long as the
-        payload): paged leaves reshape back to one batch-1 block-aligned
-        prefill and reuse the donated :func:`serve_kv_insert_blocks`
+        payload): paged leaves view as one batch-1 block-aligned prefill
+        and reuse the donated :func:`serve_kv_insert_blocks`
         scatter into the slot's own granted blocks; slot-state leaves
         scatter by slot id.  Byte-for-byte: export -> wire -> import
         preserves every leaf exactly (tests/test_transport.py), which is

@@ -132,27 +132,45 @@ def test_fused_mlp_matches_composed(key, label, act, monkeypatch):
     _assert_close(got, want, "mlp")
 
 
-def test_kv_scatter_matches_and_sentinel_writes_nothing(key):
-    L, nb, bs, KV, hd, bc = 2, 8, 4, 2, 16, 3
+@pytest.mark.parametrize("tiled", [False, True], ids=["all-layers", "tiled"])
+@pytest.mark.parametrize("blk", [[1, 8, 5], [8, 8, 1, 8, 5, 8],
+                                 [8, 8, 8]],
+                         ids=["middle", "around", "all"])
+def test_kv_scatter_matches_and_sentinel_writes_nothing(key, blk, tiled,
+                                                        monkeypatch):
+    """Rows (L, bc, KV, hd) into the lane-dense (L, nb, bs, KV*hd) pool;
+    sentinel rows (id nb) before, between and after live rows, or every
+    row a sentinel; one block over all layers, or (the VMEM budget
+    shrunk) two layer tiles."""
+    from repro.kernels.fused_decode import kernel as K
+    L, nb, bs, KV, hd = 4, 8, 4, 2, 16
+    if tiled:
+        monkeypatch.setattr(K, "_TILE_BUDGET", 8 * 2 * bs * KV * hd * 2)
+    assert K._layer_tile(L, bs * KV * hd * 2) == (2 if tiled else L)
+    bc = len(blk)
     ks = jax.random.split(key, 3)
-    k_pool = jax.random.normal(ks[0], (L, nb, bs, KV, hd), jnp.bfloat16)
+    k_pool = jax.random.normal(ks[0], (L, nb, bs, KV * hd), jnp.bfloat16)
     v_pool = k_pool * 0.5
     k_rows = jax.random.normal(ks[1], (L, bc, KV, hd), jnp.bfloat16)
     v_rows = jax.random.normal(ks[2], (L, bc, KV, hd), jnp.bfloat16)
-    blk = jnp.asarray([1, nb, 5], jnp.int32)       # row 1 is a sentinel
-    off = jnp.asarray([2, 0, 3], jnp.int32)
+    blk = jnp.asarray(blk, jnp.int32)
+    off = jnp.arange(bc, dtype=jnp.int32) % bs
     want = ref_kv_scatter(blk, off, k_rows, v_rows, k_pool, v_pool)
     got = kv_scatter(blk, off, k_rows, v_rows, k_pool, v_pool,
                      interpret=True)
     for g, w in zip(got, want):
         assert bool(jnp.array_equal(g, w))
-    # sentinel semantics explicitly: every pool bit outside the two
-    # written cells survives, including everything the sentinel row
-    # would have addressed
-    gk = got[0]
-    mask = jnp.ones((L, nb, bs), bool).at[:, blk[0], off[0]].set(
-        False).at[:, blk[2], off[2]].set(False)
-    assert bool(jnp.array_equal(gk[mask], k_pool[mask]))
+    # sentinel semantics explicitly: every pool bit outside the written
+    # cells survives, including everything a sentinel row would have
+    # addressed; the written cells hold the rows' KV*hd lanes
+    mask = jnp.ones((L, nb, bs), bool)
+    for b in range(bc):
+        if int(blk[b]) < nb:
+            mask = mask.at[:, blk[b], off[b]].set(False)
+            assert bool(jnp.array_equal(got[0][:, blk[b], off[b]],
+                                        k_rows[:, b].reshape(L, -1)))
+    for g, p in zip(got, (k_pool, v_pool)):
+        assert bool(jnp.array_equal(g[mask], p[mask]))
 
 
 # ---------------------------------------------------------------------------
@@ -162,7 +180,7 @@ def test_kv_scatter_matches_and_sentinel_writes_nothing(key):
 def _cohort_state(cfg, bc, *, nb=16, bs=4, W=6, seed=7, sentinel=True,
                   lengths=None, tables=None):
     L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-    kp = jax.random.normal(jax.random.PRNGKey(seed), (L, nb, bs, KV, hd),
+    kp = jax.random.normal(jax.random.PRNGKey(seed), (L, nb, bs, KV * hd),
                            cfg.compute_dtype)
     pool = ((kp, kp * 0.5),)
     tokens = (jnp.arange(bc)[:, None] % 50 + 3).astype(jnp.int32)
