@@ -11,8 +11,7 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export JAX_PLATFORMS=cpu
 
 echo "== tier-1 pytest =="
-# the two seed-era deselects (jamba hybrid decode drift, q4 decode top-1
-# agreement) are fixed — the full suite runs with no exclusions
+# the full suite, no exclusions
 python -m pytest -x -q "$@"
 
 echo "== docs lint (core docstrings + README quickstart smoke) =="
@@ -77,18 +76,6 @@ echo "== fleet battery-simulation smoke: telemetry-priced devices =="
 # states (per-device PMU under one PowerPolicy, modality profile priced
 # from the modeled telemetry ledger) and report fleet tokens/s, J/token
 # and a survival-hours histogram; asserts enforced by --smoke
-BENCH_JSON="BENCH_$(python -c 'from repro.telemetry.writer import CURRENT_PR; print(CURRENT_PR)').json"
-python -m repro.launch.fleet_sim --smoke --bench-json "$BENCH_JSON"
-
-echo "== benchmark ledger + regression gate: $BENCH_JSON =="
-# the versioned bench trajectory: fused cohort-decode (bit-identical
-# pallas step; gates on the modeled HBM weight-traffic ratio and on
-# cohort batching staying a real speedup) and the fused dequant-GEMM
-# kernel (analytic traffic ratio), folded into the same BENCH_<pr>.json
-# as the fleet metrics above, then regression-gated against the last
-# committed baseline
-python -m benchmarks.bench_decode --smoke --bench-json "$BENCH_JSON"
-python -m benchmarks.bench_kernels --smoke --bench-json "$BENCH_JSON"
-python scripts/bench_gate.py "$BENCH_JSON"
+python -m repro.launch.fleet_sim --smoke
 
 echo "OK: check passed"

@@ -14,7 +14,7 @@ the same lowering) — brick params live host-side (numpy) and every
 callable the serving engine uses, then deletes the device buffers before
 the next brick loads.  There is no per-kind dispatch here; the dataflow is
 the bricks' declared ports.  The high-water-mark trace proves the
-max-not-sum claim (benchmarks/fig8_power.py and tests/test_cascade.py).
+max-not-sum claim (tests/test_cascade.py).
 """
 from __future__ import annotations
 

@@ -12,8 +12,7 @@ FP16/INT8, decoder W4A16 or W2A16, embedding FP16).  This module provides
 * :func:`quantize_tree` / :func:`dequantize_tree` — per-brick application
   driven by a :class:`QuantPolicy` (the paper's ``em-fp16 vis-fp16 dec-q4f16``
   label format);
-* weight-memory accounting used by the scheduler's cost model and the
-  Fig. 5 memory benchmark.
+* weight-memory accounting used by the scheduler's cost model.
 
 Packing layout: codes are packed along the **last** axis, ``32 // bits``
 values per int32 word, with per-group scales over contiguous groups of the

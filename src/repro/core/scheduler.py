@@ -8,7 +8,8 @@ parallel floating-point".  Two instantiations share one cost model:
 * **Edge profile** (the paper's RK3566): NPU / GPU / CPU accelerators with
   the paper's constraints — the NPU only takes *static-shape* bricks
   (§NPU: recompiling on shape change is impractical) and prefers low-bit;
-  the CPU is the fallback.  Used by the Fig. 5/6/8 benchmarks.
+  the CPU is the fallback.  Used by the fleet simulator's modeled
+  profile (``launch/fleet_sim.py``) and the quickstart example.
 
 * **Pod profile** (this repo's target): a TPU pod is silicon-homogeneous,
   so accelerator heterogeneity becomes *profile heterogeneity* —

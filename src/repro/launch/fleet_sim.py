@@ -7,8 +7,7 @@ prefill / decode) from a telemetry :class:`~repro.telemetry.ledger.Ledger`:
 * ``--profile modeled`` (default) prices the paper's full edge pipeline
   (decomposed llava-onevision graph incl. the real SigLip-class vision
   encoder) through the scheduler's energy-objective placement and
-  ``Ledger.modeled`` — deterministic across machines, which is what lets
-  the fleet metrics carry a tight regression gate in ``BENCH_<pr>.json``;
+  ``Ledger.modeled`` — deterministic across machines;
 * ``--profile ledger --ledger FILE`` characterizes from a measured
   ledger a bench run saved (``samples > 0`` rows included);
 * ``--profile default`` uses the RK3566-class fallback constants.
@@ -20,7 +19,7 @@ power states seen, positive fleet J/token, deaths recorded) enforced.
 
     PYTHONPATH=src python -m repro.launch.fleet_sim --smoke
     PYTHONPATH=src python -m repro.launch.fleet_sim --devices 512 \
-        --hours 12 [--out fleet.csv] [--bench-json BENCH_8.json]
+        --hours 12
 """
 from __future__ import annotations
 
@@ -28,8 +27,8 @@ import argparse
 import dataclasses
 import sys
 
-# fig8's event shape: SigLip-so400m patches per frame, a short prompt,
-# a short voice answer
+# the paper's Fig. 8 event shape: SigLip-so400m patches per frame, a
+# short prompt, a short voice answer
 VISION_TOKENS = 729
 SIGLIP_PARAMS = 400e6
 PREFILL_TOKENS = 64
@@ -38,8 +37,8 @@ SMOKE = dict(devices=128, hours=2.0, dt=10.0, battery_mah=150.0)
 
 def _paper_pipeline(arch: str = "llava-onevision-0.5b"):
     """The full edge pipeline with the REAL vision-encoder brick swapped
-    in for the stub frontend and analytic param_bytes filled (fig8's
-    idiom) — so the modeled ledger prices what the paper deploys."""
+    in for the stub frontend and analytic param_bytes filled — so the
+    modeled ledger prices what the paper deploys."""
     from repro.configs import get_config
     from repro.core.bricks import Brick, Port, decompose
 
@@ -71,7 +70,7 @@ def modeled_profile():
     accel_for = {b: by_name[a] for b, a in pl.assignment.items()}
     led = Ledger.modeled(g, accel_for, phase_tokens={
         "stage": VISION_TOKENS, "prefill": PREFILL_TOKENS, "decode": 1})
-    return ModalityProfile.from_ledger(led), led
+    return ModalityProfile.from_ledger(led)
 
 
 def main(argv=None) -> int:
@@ -98,27 +97,19 @@ def main(argv=None) -> int:
                          f"three power states and device death happen "
                          f"inside a {SMOKE['hours']:.0f} h horizon; "
                          f"enforces the acceptance assertions")
-    ap.add_argument("--out", default=None,
-                    help="also write the summary rows to this CSV "
-                         "(CI artifact)")
-    ap.add_argument("--bench-json", default=None,
-                    help="fold rows/gated metrics/modeled ledger into "
-                         "this versioned BENCH_<pr>.json")
     args = ap.parse_args(argv)
     if args.smoke:
         args.devices = max(args.devices, SMOKE["devices"])
         args.hours, args.dt = SMOKE["hours"], SMOKE["dt"]
         args.battery_mah = SMOKE["battery_mah"]
 
-    led = None
     if args.profile == "modeled":
-        profile, led = modeled_profile()
+        profile = modeled_profile()
     elif args.profile == "ledger":
         if not args.ledger:
             ap.error("--profile ledger needs --ledger FILE")
         from repro.telemetry.ledger import Ledger
-        led = Ledger.load(args.ledger)
-        profile = ModalityProfile.from_ledger(led)
+        profile = ModalityProfile.from_ledger(Ledger.load(args.ledger))
     else:
         profile = ModalityProfile.default_edge()
     print(f"profile ({args.profile}): "
@@ -129,33 +120,6 @@ def main(argv=None) -> int:
                          battery_mah=args.battery_mah, dt_s=args.dt)
     rep = sim.run(args.hours)
     print(rep.summary())
-
-    rows = [
-        ("fleet/tokens_per_s", 0.0, f"{rep.tokens_per_s:.2f}"),
-        ("fleet/j_per_token", 0.0, f"{rep.j_per_token:.5f}"),
-        ("fleet/survival_p50_h", 0.0, f"{rep.survival_hours_p50:.3f}"),
-        ("fleet/dead", 0.0, f"{rep.dead}/{rep.n_devices}"),
-        ("fleet/states", 0.0, " ".join(sorted(rep.states_seen))),
-        ("fleet/shed_tokens", 0.0, f"{rep.shed_tokens:.0f}"),
-    ]
-    if args.out or args.bench_json:
-        from repro.telemetry import writer
-        if args.out:
-            writer.write_csv(args.out, rows)
-        if args.bench_json:
-            # simulated time over a modeled energy integral: these are
-            # machine-independent, so they carry the 10% regression gate
-            writer.merge_section(
-                args.bench_json, "fleet", rows=rows,
-                metrics={
-                    "fleet_tokens_per_s": writer.metric(
-                        rep.tokens_per_s, better="higher", gate=True),
-                    "fleet_j_per_token": writer.metric(
-                        rep.j_per_token, better="lower", gate=True),
-                    "survival_hours_p50": writer.metric(
-                        rep.survival_hours_p50, better="higher",
-                        gate=True)},
-                ledger=led)
 
     if args.smoke:
         all_states = {s.value for s in PowerState}

@@ -122,7 +122,7 @@ from repro.core.power import BatteryAwareExecutor, PMU, PowerState
 from repro.core.scheduler import class_staging_budgets, kv_block_budgets
 from repro.core.tabm import SlotClassPool, TABMError
 from repro.models import model as M
-from repro.serving.kv_cache import PagedKVCache, SlotCache, bucket_length
+from repro.serving.kv_cache import PagedKVCache, bucket_length
 from repro.serving.sampling import greedy, sample_rows
 from repro.telemetry.calibration import CostCalibration
 from repro.telemetry.ledger import Ledger
@@ -429,7 +429,6 @@ class ServingEngine:
                  stage_batch: Optional[int] = None,
                  aging_steps: int = 32, block_size: int = 64,
                  kv_blocks: Optional[int] = None,
-                 max_cohort: Optional[int] = None,
                  share_staged: bool = True,
                  calibration: Optional[CostCalibration] = None,
                  capture_slab: bool = False,
@@ -453,10 +452,6 @@ class ServingEngine:
                                   block_size=block_size,
                                   total_blocks=kv_blocks)
         self.max_len = max_len
-        # cohort cap (None = every live slot decodes each step); when
-        # capped, a rotating pointer keeps the excluded rows fair
-        self.max_cohort = max_cohort
-        self._rotate = 0
         self.executor = executor or BatteryAwareExecutor(PMU())
         # staging microbatch override; None = min(arch max_stage_batch,
         # battery Knobs.max_stage_batch), always clamped to ring capacity
@@ -687,15 +682,9 @@ class ServingEngine:
         return min(1 << max(0, n - 1).bit_length(), self.slots.n_slots)
 
     def _cohort_slots(self) -> List[int]:
-        """The slots decoding this step.  Uncapped: every live slot —
-        ONE batched call serves the whole fleet.  Capped (max_cohort): a
-        rotating window so excluded rows are never starved."""
-        slots = sorted(self.live)
-        if self.max_cohort is not None and len(slots) > self.max_cohort:
-            k = self._rotate % len(slots)
-            slots = (slots[k:] + slots[:k])[: self.max_cohort]
-            self._rotate += self.max_cohort
-        return slots
+        """The slots decoding this step: every live slot — ONE batched
+        call serves the whole fleet."""
+        return sorted(self.live)
 
     def _cohort_fn(self, bc: int):
         """The compiled cohort decode step for cohort-size bucket `bc`

@@ -18,9 +18,6 @@ ledger"):
   simulator stepping hundreds of battery devices (own PMU/PowerPolicy
   each) through request traces, reporting fleet tokens/s, J/token and
   survival-hours histograms.
-* :mod:`repro.telemetry.writer` — the ONE benchmark emitter: CSV
-  side-emit plus the versioned ``BENCH_<pr>.json`` ledger that
-  ``scripts/bench_gate.py`` regression-gates in CI.
 """
 from repro.telemetry.calibration import CostCalibration
 from repro.telemetry.ledger import Ledger, PhaseRecord

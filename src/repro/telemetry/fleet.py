@@ -19,9 +19,8 @@ same file a bench run wrote drives the fleet.
 
 Determinism: the only randomness is the per-device offered-load draw at
 construction (seeded); stepping is pure arithmetic with a fractional
-arrival accumulator — same seed, same fleet, same report, which is what
-lets ``BENCH_<pr>.json`` gate fleet tokens/s and J/token at a tight
-tolerance across machines.
+arrival accumulator — same seed, same fleet, same report on any
+machine.
 """
 from __future__ import annotations
 

@@ -127,5 +127,5 @@ def test_quantized_model_still_predicts(key):
                      .astype(jnp.float32))
     # random-init logits are near-uniform so q4 flips many argmaxes; the
     # signal is agreement FAR above chance (1/512).  Trained-model quality
-    # is validated in benchmarks/fig7 and tests/test_serve_quant.py.
+    # is validated in tests/test_serve_quant.py.
     assert float(agree) > 100.0 / cfg.vocab_size

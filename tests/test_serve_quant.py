@@ -12,22 +12,43 @@ from repro.launch.steps import abstract_params, init_params
 from repro.models import model as M
 
 
+# max|decode - forward| / max|forward| allowed between the q-model's
+# cached decode path and its own full forward.  Measured 0.0065-0.0099
+# over seeds 0-7; the control (fp params in the decode path, against the
+# q forward) reads 0.26-0.45, so this sits >10x below quantization error.
+DECODE_FORWARD_TOL = 0.02
+
+
 def _top1(logits):
     return int(jnp.argmax(logits.reshape(logits.shape[0], -1)[0], -1))
+
+
+def _teacher_forced(params, cfg, full, S, steps):
+    """Logits for positions S-1 .. S+steps-2 of ``full``: prefill its first
+    S tokens, then feed the rest one decode step at a time."""
+    lg, cache = M.lm_prefill(params, cfg, full[:, :S], 40)
+    out = [lg.reshape(-1)]
+    for i in range(steps - 1):
+        lg, cache = M.lm_decode_step(params, cfg, full[:, S + i:S + i + 1],
+                                     cache)
+        out.append(lg.reshape(-1))
+    return jnp.stack(out)
 
 
 def test_quantized_decode_runs_and_tracks_fp(key):
     """QTensor params flow through prefill + decode, and q4 tracks fp.
 
-    The seed version of this test free-ran BOTH models on their own argmax
-    and demanded the trajectories match — but a random-init model has
-    near-uniform logits, so one flipped top-1 forks the sequences and the
-    comparison measures trajectory chaos, not quantization error (the test
-    was deselected for exactly that).  The sound properties:
+    A random-init model has near-uniform logits, so comparing free-running
+    argmax trajectories measures top-1 near-ties, not correctness.  The
+    sound properties:
 
-    * self-consistency — the q-model's free-running decode reproduces its
-      own full-forward argmax exactly (prefill+decode path correctness
-      with QTensor params, the thing the seed test actually exercised);
+    * self-consistency — teacher-forced through prefill + cached decode,
+      the q-model's logits match its own full forward within
+      ``DECODE_FORWARD_TOL`` (prefill+decode path correctness with
+      QTensor params), and top-1 agrees wherever the forward's top-1 leads
+      top-2 by more than twice the measured gap (there it is decidable);
+      the same comparison with fp params in the decode path must fail the
+      tolerance, so it detects quantization-sized error;
     * teacher-forced tracking — the SAME tokens through both models keep
       the q4 logits within a calibrated relative error of fp, with top-1
       agreement far above chance (measured margins: rel <= 0.40, agree
@@ -39,7 +60,7 @@ def test_quantized_decode_runs_and_tracks_fp(key):
     tokens = (jnp.arange(24)[None] % 60 + 3).astype(jnp.int32)
     steps = 8
 
-    # --- self-consistency: free-running q decode == q full forward -------
+    # --- self-consistency: q decode path == q full forward ---------------
     lg_q, cache_q = M.lm_prefill(qparams, cfg, tokens, 40)
     seq = [_top1(lg_q)]
     for _ in range(steps - 1):
@@ -51,8 +72,20 @@ def test_quantized_decode_runs_and_tracks_fp(key):
         [tokens, jnp.asarray(seq[:-1], jnp.int32)[None]], axis=1)
     out_q, _ = M.lm_forward(qparams, cfg, full)
     S = tokens.shape[1]
-    replay = [int(jnp.argmax(out_q[0, S - 1 + i])) for i in range(steps)]
-    assert replay == seq                 # decode path == forward path
+    fwd = out_q[0, S - 1:S - 1 + steps]
+    scale = float(jnp.max(jnp.abs(fwd)))
+    dec_q = _teacher_forced(qparams, cfg, full, S, steps)
+    err = jnp.max(jnp.abs(dec_q - fwd), -1)             # per position
+    gap = float(jnp.max(err)) / scale
+    assert gap < DECODE_FORWARD_TOL, gap        # decode path == forward
+    top2 = jnp.sort(fwd, -1)[:, -2:]
+    decidable = top2[:, 1] - top2[:, 0] > 2 * err
+    assert int(decidable.sum()) >= steps // 2, decidable
+    same = jnp.argmax(dec_q, -1) == jnp.argmax(fwd, -1)
+    assert bool(jnp.all(same | ~decidable)), (same, decidable)
+    dec_f = _teacher_forced(params, cfg, full, S, steps)   # control
+    control = float(jnp.max(jnp.abs(dec_f - fwd))) / scale
+    assert control > 10 * DECODE_FORWARD_TOL, control
 
     # --- teacher-forced tracking: same inputs, compare outputs ----------
     lg_f, cache_f = M.lm_prefill(params, cfg, tokens, 40)

@@ -1,12 +1,8 @@
 """Telemetry subsystem (PR 8): ledger arithmetic + persistence, the
 measured->scheduler calibration feedback, wall-time probes on the
-plan/engine hot paths, the shared BENCH writer + regression gate, and
-the fleet-scale battery simulator (incl. PMU/PowerPolicy replay from a
-recorded fleet trace)."""
+plan/engine hot paths, and the fleet-scale battery simulator (incl.
+PMU/PowerPolicy replay from a recorded fleet trace)."""
 import dataclasses
-import importlib.util
-import json
-import os
 import time
 
 import numpy as np
@@ -24,11 +20,8 @@ from repro.core.tabm import SlotClassPool
 from repro.launch.steps import init_params
 from repro.serving.engine import Request, ServingEngine, TraceEvent
 from repro.telemetry import CostCalibration, Ledger, PhaseRecord, WallProbe
-from repro.telemetry import writer
 from repro.telemetry.fleet import (FleetSimulator, ModalityProfile,
                                    replay_trace)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _graph(arch="llava-onevision-0.5b"):
@@ -549,110 +542,3 @@ def test_fleet_trace_replays_through_fresh_pmu_policy():
         for (state, level), ev in zip(got, evs):
             assert state == ev.state, (dev, ev)
             assert level == pytest.approx(ev.level, abs=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# shared writer + regression gate
-# ---------------------------------------------------------------------------
-
-def test_writer_merge_sections_and_ledger(tmp_path):
-    path = str(tmp_path / "BENCH_8.json")
-    led_a = Ledger()
-    led_a.accumulate("decoder", "decode", seconds=1.0, tokens=10, samples=1)
-    writer.merge_section(path, "alpha", rows=[("a/x", 1.0, "d=1")],
-                         metrics={"m": writer.metric(2.0, gate=False)},
-                         ledger=led_a)
-    led_b = Ledger()
-    led_b.accumulate("decoder", "decode", seconds=1.0, tokens=10, samples=1)
-    data = writer.merge_section(
-        path, "beta", rows=[("b/y", 2.0, "d=2")],
-        metrics={"g": writer.metric(5.0, better="lower")}, ledger=led_b)
-    # separate processes accumulate into ONE file
-    assert set(data["sections"]) == {"alpha", "beta"}
-    assert data["sections"]["alpha"]["rows"] == [["a/x", 1.0, "d=1"]]
-    merged = Ledger.from_dict(data["ledger"])
-    assert merged.record("decoder", "decode").tokens == 20
-    assert merged.record("decoder", "decode").samples == 2
-    # only gate:true metrics are gateable
-    assert list(writer.gated_metrics(data)) == ["beta/g"]
-    # a foreign-PR file is restarted, not merged into
-    data2 = writer.merge_section(path, "gamma", rows=[], pr=99)
-    assert set(data2["sections"]) == {"gamma"} and data2["pr"] == 99
-    # csv side-emit and round-trip
-    csv = tmp_path / "rows.csv"
-    writer.write_csv(str(csv), [("a/x", 1.0, "d=1")])
-    assert csv.read_text().splitlines()[0] == writer.CSV_HEADER
-    assert writer.read_bench(path)["sections"]["gamma"] == {"rows": []}
-
-
-def test_latest_baseline_picks_highest_and_excludes_candidate(tmp_path):
-    for n in (3, 8, 12):
-        (tmp_path / f"BENCH_{n}.json").write_text("{}")
-    (tmp_path / "BENCH_x.json").write_text("{}")
-    assert writer.latest_baseline(str(tmp_path)).endswith("BENCH_12.json")
-    assert writer.latest_baseline(
-        str(tmp_path),
-        exclude=str(tmp_path / "BENCH_12.json")).endswith("BENCH_8.json")
-    assert writer.latest_baseline(str(tmp_path / "empty")) is None
-
-
-def _bench_gate():
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate", os.path.join(REPO, "scripts", "bench_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_data(**metrics):
-    return {"schema": 1, "pr": 8,
-            "sections": {"s": {"metrics": metrics}}, "ledger": None}
-
-
-def test_bench_gate_compare():
-    gate = _bench_gate()
-    base = _bench_data(tps=writer.metric(100.0, better="higher"),
-                       jpt=writer.metric(0.04, better="lower"),
-                       wall=writer.metric(123.0, gate=False))
-    # within tolerance both directions -> pass (ungated ignored entirely)
-    ok, _ = gate.compare(base, _bench_data(
-        tps=writer.metric(95.0), jpt=writer.metric(0.043),
-        wall=writer.metric(9999.0, gate=False)))
-    assert ok
-    # >10% tokens/s drop -> fail
-    ok, lines = gate.compare(base, _bench_data(
-        tps=writer.metric(80.0), jpt=writer.metric(0.04)))
-    assert not ok and any(line.startswith("FAIL s/tps") for line in lines)
-    # >10% J/token rise -> fail
-    ok, _ = gate.compare(base, _bench_data(
-        tps=writer.metric(100.0), jpt=writer.metric(0.05, better="lower")))
-    assert not ok
-    # a dropped gated metric fails unless explicitly allowed
-    ok, _ = gate.compare(base, _bench_data(tps=writer.metric(100.0)))
-    assert not ok
-    ok, _ = gate.compare(base, _bench_data(tps=writer.metric(100.0)),
-                         allow_missing=True)
-    assert ok
-    # empty baseline gates nothing
-    ok, lines = gate.compare(_bench_data(), _bench_data())
-    assert ok and "no gated metrics" in lines[-1]
-
-
-def test_committed_bench_parses_and_self_gates():
-    """The committed BENCH_<CURRENT_PR>.json was produced through the
-    shared writer: it parses, carries gated metrics + a ledger, and
-    gates cleanly against itself."""
-    path = os.path.join(REPO, f"BENCH_{writer.CURRENT_PR}.json")
-    assert os.path.exists(path), \
-        f"BENCH_{writer.CURRENT_PR}.json must be committed"
-    data = writer.read_bench(path)
-    assert data["schema"] == writer.SCHEMA
-    assert data["pr"] == writer.CURRENT_PR
-    gated = writer.gated_metrics(data)
-    assert gated, "the committed ledger must carry gateable metrics"
-    assert any(k.startswith("fleet/") for k in gated)
-    led = Ledger.from_dict(data["ledger"])
-    assert len(led) > 0
-    json.dumps(data)                            # fully JSON-serializable
-    ok, _ = _bench_gate().compare(data, data)
-    assert ok, "a ledger must never regress against itself"
